@@ -813,6 +813,15 @@ def main():
         print("RESULT " + json.dumps(
             bench_sharded(args.rows, args.repeats, args.block)))
         return
+    if args.sharded:
+        import jax
+        if jax.default_backend() != "cpu":
+            # the child forces 8 *host* devices; on an accelerator host it
+            # would also contend with this process for the chip
+            raise SystemExit(
+                f"--sharded simulates devices on the CPU and cannot run on "
+                f"a {jax.default_backend()} host; run the sharded path on "
+                "the chips with `python chip_smoke.py --chips 4`")
 
     table = make_forest_table(args.rows, n_dup=2, seed=7)
     rng = np.random.default_rng(0)

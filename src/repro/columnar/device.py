@@ -471,26 +471,23 @@ class DeviceTapeBackend(SetBackend):
     block:     records per block (multiple of 32; the padded block count is
                bucketed to a power of two for jit-cache sharing)
     kernels:   "jax" = pure-jnp ops fused by XLA; "pallas" = the Pallas
-               kernels (interpret mode off-TPU)
-    interpret: force Pallas interpret mode (default: auto-detect non-TPU)
+               kernels (interpret mode off-TPU, see
+               :func:`repro.kernels.ops.interpret_mode`)
     """
 
     def __init__(self, table: Table, block: int = 8192,
-                 kernels: str = "jax", interpret: Optional[bool] = None,
-                 zone_prune: bool = True):
+                 kernels: str = "jax", zone_prune: bool = True):
         if block % WORD:
             raise ValueError("block must be a multiple of 32")
         if kernels not in ("jax", "pallas"):
             raise ValueError(f"unknown kernels {kernels!r}")
-        import jax
+        from ..kernels.ops import interpret_mode
         self.table = table
         self.n = table.n_records
         self.block = block
         self.kernels = kernels
         self.pallas = kernels == "pallas"
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
-        self.interpret = interpret
+        self.interpret = interpret_mode()
         self.wpb = block // WORD
         self.nblocks = next_pow2((self.n + block - 1) // block)
         self._padded = self.nblocks * block

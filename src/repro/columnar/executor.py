@@ -13,7 +13,7 @@ bitmaps* (vs the proof-object vertex sets):
                      (block skipping = the paper's count(D) cost, block
                      granular, cf. BlockCostModel).  ``engine="jax"`` uses
                      the pure-jnp reference, ``engine="pallas"`` the Pallas
-                     kernel (interpret mode on CPU).
+                     kernel (interpret mode off-TPU).
 
 Both plug into BestDMachine / ShallowFish / NoOrOpt unchanged.
 """
@@ -260,6 +260,8 @@ class JaxBlockBackend(_HostOpLog, SetBackend):
         self.n = table.n_records
         self.block = block
         self.engine = engine
+        from ..kernels.ops import interpret_mode
+        self.interpret = interpret_mode()
         self.stats = Stats()
         self.blocks_touched = 0
         self.records_touched = 0.0
@@ -365,7 +367,8 @@ class JaxBlockBackend(_HostOpLog, SetBackend):
         uw2d = jnp.asarray(uw.reshape(self.nblocks, wpb))
         if self.engine == "pallas":
             from ..kernels import ops as kops
-            _, pops = kops.bitmap_op(uw2d, uw2d, 0, interpret=True)
+            _, pops = kops.bitmap_op(uw2d, uw2d, 0,
+                                     interpret=self.interpret)
         else:
             from ..kernels import ref as kref
             pops = kref.popcount_ref(uw2d)
@@ -458,7 +461,8 @@ class JaxBlockBackend(_HostOpLog, SetBackend):
                 if self.engine == "pallas":
                     from ..kernels import ops as kops
                     res = kops.predicate_blocks(col_live, bits_live, value,
-                                                opcode, interpret=True)
+                                                opcode,
+                                                interpret=self.interpret)
                 else:
                     from ..kernels import ref as kref
                     res = kref.predicate_blocks_ref(col_live, bits_live,
@@ -472,9 +476,9 @@ class JaxBlockBackend(_HostOpLog, SetBackend):
                 bits_live = jnp.asarray(bits_live)
                 if self.engine == "pallas":
                     from ..kernels import ops as kops
-                    res = kops.predicate_blocks_multi(col_live, bits_live,
-                                                      value, opcode,
-                                                      interpret=True)
+                    res = kops.predicate_blocks_multi(
+                        col_live, bits_live, value, opcode,
+                        interpret=self.interpret)
                 else:
                     from ..kernels import ref as kref
                     res = kref.predicate_blocks_multi_ref(col_live, bits_live,

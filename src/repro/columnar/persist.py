@@ -23,6 +23,9 @@ inputs that survive restarts unchanged, so all three persist:
   (``jax_compilation_cache_dir``): the whole-tape programs' XLA
   executables are content-addressed by HLO hash, so a restarted server's
   first drain skips compilation too (measured ≥3x in the ``--slo`` bench).
+  The cache lives where ``JAX_COMPILATION_CACHE_DIR`` says, or else at the
+  fixed ``<checkout>/.jax_cache`` — never under a session's ``cache_dir``:
+  the directory is part of what a later process must find again.
 
 Loads are best-effort by design: a corrupt/stale/foreign cache file must
 never take a serving process down, so every reader validates a format
@@ -58,7 +61,12 @@ FORMAT = 2
 PLAN_CACHE_FILE = "plan_cache.pkl"
 FEEDBACK_FILE = "feedback.pkl"
 METRICS_FILE = "metrics.json"
-XLA_CACHE_DIR = "xla"
+
+#: the persistent XLA cache's home when ``JAX_COMPILATION_CACHE_DIR`` is
+#: not set: a fixed directory at the checkout root (``.gitignore`` lists it)
+DEFAULT_XLA_CACHE = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "..", "..",
+    ".jax_cache"))
 
 
 def _dump_checked(obj, path: str, epoch: Optional[str] = None) -> None:
@@ -184,35 +192,30 @@ def load_feedback(path: str,
     return store if isinstance(store, FeedbackStore) else None
 
 
-_XLA_CACHE_WIRED: Optional[str] = None
+def compilation_cache_dir() -> str:
+    """Where the persistent compilation cache lives: the directory
+    ``JAX_COMPILATION_CACHE_DIR`` names, else :data:`DEFAULT_XLA_CACHE`."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_XLA_CACHE
 
 
-def enable_compilation_cache(cache_dir: str) -> bool:
-    """Point JAX's persistent compilation cache at ``cache_dir`` so jitted
-    whole-tape programs persist across processes (content-addressed by HLO
-    hash — restarts with unchanged tape structure skip XLA entirely).
-    Thresholds drop to zero: serving cares about the 1.5 s cold tape, not
-    disk frugality.  Global (JAX config is process-wide); repeat calls
-    with the same directory are no-ops, a different directory rewires."""
-    global _XLA_CACHE_WIRED
-    path = os.path.join(cache_dir, XLA_CACHE_DIR)
-    if _XLA_CACHE_WIRED == path:
-        return True
-    try:
-        import jax
+def enable_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache so jitted whole-tape
+    programs persist across processes (content-addressed by HLO hash —
+    restarts with unchanged tape structure skip XLA entirely); returns its
+    directory (:func:`compilation_cache_dir`).  JAX already reads
+    ``JAX_COMPILATION_CACHE_DIR`` itself, so the directory is set here only
+    when that variable is absent.  Thresholds drop to zero: serving cares
+    about the cold tape, not disk frugality.  Global (JAX config is
+    process-wide) and idempotent; a failure to wire it raises."""
+    import jax
+    path = compilation_cache_dir()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         os.makedirs(path, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        try:
-            jax.config.update("jax_persistent_cache_enable_xla_caches",
-                              "all")
-        except Exception:
-            pass                    # older jax: core cache still works
-    except Exception:
-        return False
-    _XLA_CACHE_WIRED = path
-    return True
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
+    return path
 
 
 def save_session_caches(session: QuerySession, cache_dir: str,
@@ -247,7 +250,8 @@ def load_session_caches(session: QuerySession, cache_dir: str,
                         compilation_cache: bool = True,
                         epoch: Optional[str] = None) -> dict:
     """Warm a fresh session from ``cache_dir`` (and wire the persistent
-    compilation cache); returns counts.  Safe on an empty/missing
+    compilation cache, see :func:`enable_compilation_cache`); returns
+    counts.  Safe on an empty/missing
     directory — everything cold-starts.  ``epoch`` is the expected data
     lineage: files stamped with a *different* one are refused (clean cold
     start) instead of warming the session with foreign-table state."""
@@ -259,5 +263,5 @@ def load_session_caches(session: QuerySession, cache_dir: str,
         session.feedback.__dict__.update(fb.__dict__)
         out["feedback_keys"] = len(fb._keys)
     if compilation_cache:
-        out["compilation_cache"] = enable_compilation_cache(cache_dir)
+        out["compilation_cache"] = enable_compilation_cache()
     return out

@@ -45,8 +45,6 @@ jax import — see ``tests/test_shard.py`` for the subprocess pattern.
 """
 from __future__ import annotations
 
-from typing import Optional
-
 import numpy as np
 
 from ..runtime import faults as _faults
@@ -70,8 +68,8 @@ class ShardedTapeBackend(DeviceTapeBackend):
     """
 
     def __init__(self, table: Table, block: int = 8192,
-                 kernels: str = "jax", interpret: Optional[bool] = None,
-                 zone_prune: bool = True, shards: int = 1, mesh=None):
+                 kernels: str = "jax", zone_prune: bool = True,
+                 shards: int = 1, mesh=None):
         if kernels != "jax":
             raise ConfigError(
                 f"kernels={kernels!r}: pallas kernels are not supported "
@@ -94,7 +92,7 @@ class ShardedTapeBackend(DeviceTapeBackend):
         self.mesh = mesh
         self.shards = size
         super().__init__(table, block=block, kernels="jax",
-                         interpret=interpret, zone_prune=zone_prune)
+                         zone_prune=zone_prune)
         # at least one block per shard: pad the power-of-two bucket up
         # (padding blocks carry zero bitmaps / NONE verdicts either way)
         if self.nblocks < self.shards:
@@ -171,7 +169,6 @@ class ShardedTapeBackend(DeviceTapeBackend):
         Appends never retrace here either: the zone masks stay runtime
         inputs, and the cache key only adds the mesh identity."""
         import jax
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         prune = self._zones is not None
         key = (tape.key, self.pallas, self.interpret, prune, skip,
@@ -207,12 +204,12 @@ class ShardedTapeBackend(DeviceTapeBackend):
                 zspec = P()
             else:
                 zspec = P(None, "shards")
-            return shard_map(
+            return jax.shard_map(
                 shard_body, mesh=mesh,
                 in_specs=(tuple(P("shards", None, None) for _ in cols),
                           P(), P(), zspec, P("shards", None), P("shards")),
                 out_specs=(P(), P(), P(), P(), P()),
-                check_rep=False,
+                check_vma=False,
             )(cols, values, lmasks, zmasks, full_bits, full_pops)
 
         prog = jax.jit(program)

@@ -13,7 +13,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from .predicate_scan import row_spec
 
 AND, OR, ANDNOT = range(3)
 
@@ -41,20 +42,21 @@ def bitmap_setop(a: jnp.ndarray, b: jnp.ndarray, opcode: int,
     """a, b: u32[N, W] -> (u32[N, W] result, i32[N, 1] per-row popcounts)."""
     n, w = a.shape
     kernel = functools.partial(_bitmap_kernel, opcode=opcode)
-    return pl.pallas_call(
+    out, pops = pl.pallas_call(
         kernel,
         grid=(n,),
         in_specs=[
-            pl.BlockSpec((1, w), lambda i: (i, 0)),
-            pl.BlockSpec((1, w), lambda i: (i, 0)),
+            row_spec(w, lambda i: (i, 0, 0)),
+            row_spec(w, lambda i: (i, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, w), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            row_spec(w, lambda i: (i, 0, 0)),
+            pl.BlockSpec((None, 1, 1), lambda i: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n, w), jnp.uint32),
-            jax.ShapeDtypeStruct((n, 1), jnp.int32),
+            jax.ShapeDtypeStruct((n, 1, w), jnp.uint32),
+            jax.ShapeDtypeStruct((n, 1, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(a, b)
+    )(a.reshape(n, 1, w), b.reshape(n, 1, w))
+    return out.reshape(n, w), pops.reshape(n, 1)

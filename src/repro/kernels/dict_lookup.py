@@ -29,6 +29,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import ref
+from .predicate_scan import query_chunks, row_spec
+
 
 def _lookup_kernel(pop_ref, mask_ref, col_ref, bits_ref, out_ref, *,
                    n_mask_words: int):
@@ -50,9 +53,7 @@ def _lookup_kernel(pop_ref, mask_ref, col_ref, bits_ref, out_ref, *,
             sel = word_ix == u
             b = ((word >> code_bit) & jnp.uint32(1)).astype(jnp.bool_)
             hit = jnp.logical_or(hit, jnp.logical_and(sel, b))
-        keep = jnp.logical_and(hit, in_set)
-        out_ref[...] = (keep.astype(jnp.uint32) << bitpos).sum(
-            axis=0, keepdims=True, dtype=jnp.uint32)
+        out_ref[...] = ref.pack_bitmajor(jnp.logical_and(hit, in_set))
 
     @pl.when(pop_ref[i] == 0)
     def _dead():
@@ -74,16 +75,16 @@ def dict_lookup_scan(col_bitmajor: jnp.ndarray, bits: jnp.ndarray,
         grid=(n,),
         in_specs=[
             pl.BlockSpec((1, 32, w), lambda i, pop, mask: (i, 0, 0)),
-            pl.BlockSpec((1, w), lambda i, pop, mask: (i, 0)),
+            row_spec(w, lambda i, pop, mask: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, w), lambda i, pop, mask: (i, 0)),
+        out_specs=row_spec(w, lambda i, pop, mask: (i, 0, 0)),
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, w), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((n, 1, w), jnp.uint32),
         interpret=interpret,
-    )(pops, mask_words, col_bitmajor, bits)
+    )(pops, mask_words, col_bitmajor, bits.reshape(n, 1, w)).reshape(n, w)
 
 
 def dict_lookup_scan_multi(col_bitmajor: jnp.ndarray, bits: jnp.ndarray,
@@ -94,23 +95,30 @@ def dict_lookup_scan_multi(col_bitmajor: jnp.ndarray, bits: jnp.ndarray,
     col_bitmajor: f32[N, 32, W];  bits: u32[Q*N, W] (query-major stacking);
     pops: i32[Q*N];  mask_words: u32[U]  ->  u32[Q*N, W].  Same index-map
     trick as ``predicate_scan_multi``: grid step ``k`` re-reads column
-    block ``k % N`` against bitmap row ``k``."""
+    block ``k % N`` against bitmap row ``k``, and the same SMEM split
+    into :func:`~repro.kernels.predicate_scan.query_chunks` ranges."""
     qn, w = bits.shape
     n = col_bitmajor.shape[0]
     u = mask_words.shape[0]
+    chunks = query_chunks(qn, n, reserved=u)
+    if len(chunks) > 1:
+        return jnp.concatenate([
+            dict_lookup_scan_multi(col_bitmajor, bits[lo:hi], pops[lo:hi],
+                                   mask_words, interpret=interpret)
+            for lo, hi in chunks])
     kernel = functools.partial(_lookup_kernel, n_mask_words=u)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(qn,),
         in_specs=[
             pl.BlockSpec((1, 32, w), lambda k, pop, mask: (k % n, 0, 0)),
-            pl.BlockSpec((1, w), lambda k, pop, mask: (k, 0)),
+            row_spec(w, lambda k, pop, mask: (k, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, w), lambda k, pop, mask: (k, 0)),
+        out_specs=row_spec(w, lambda k, pop, mask: (k, 0, 0)),
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((qn, w), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((qn, 1, w), jnp.uint32),
         interpret=interpret,
-    )(pops, mask_words, col_bitmajor, bits)
+    )(pops, mask_words, col_bitmajor, bits.reshape(qn, 1, w)).reshape(qn, w)

@@ -20,6 +20,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import ref
+from .predicate_scan import row_spec
 
 
 def _chain_kernel(pop_ref, val_ref, cols_ref, bits_ref, out_ref, *,
@@ -39,9 +40,7 @@ def _chain_kernel(pop_ref, val_ref, cols_ref, bits_ref, out_ref, *,
             acc = cmp if acc is None else (
                 jnp.logical_and(acc, cmp) if conj
                 else jnp.logical_or(acc, cmp))
-        keep = jnp.logical_and(acc, in_set)
-        out_ref[...] = (keep.astype(jnp.uint32) << bitpos).sum(
-            axis=0, keepdims=True, dtype=jnp.uint32)
+        out_ref[...] = ref.pack_bitmajor(jnp.logical_and(acc, in_set))
 
     @pl.when(pop_ref[i] == 0)
     def _dead():
@@ -63,13 +62,13 @@ def fused_chain_scan(cols_bitmajor: jnp.ndarray, bits: jnp.ndarray,
         grid=(n,),
         in_specs=[
             pl.BlockSpec((1, k, 32, w), lambda i, pop, val: (i, 0, 0, 0)),
-            pl.BlockSpec((1, w), lambda i, pop, val: (i, 0)),
+            row_spec(w, lambda i, pop, val: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, w), lambda i, pop, val: (i, 0)),
+        out_specs=row_spec(w, lambda i, pop, val: (i, 0, 0)),
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, w), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((n, 1, w), jnp.uint32),
         interpret=interpret,
-    )(pops, values, cols_bitmajor, bits)
+    )(pops, values, cols_bitmajor, bits.reshape(n, 1, w)).reshape(n, w)

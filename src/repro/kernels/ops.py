@@ -19,6 +19,13 @@ from .fused_chain import fused_chain_scan
 from .predicate_scan import predicate_scan, predicate_scan_multi
 
 
+def interpret_mode() -> bool:
+    """Whether the Pallas kernels run in interpret mode: exactly when the
+    default JAX backend is not a TPU.  The one rule every backend uses, so
+    a TPU run never silently falls into the interpreter."""
+    return jax.default_backend() != "tpu"
+
+
 @functools.partial(jax.jit, static_argnames=("opcode", "interpret"))
 def predicate_blocks(col: jnp.ndarray, bits: jnp.ndarray, value,
                      opcode: int, interpret: bool = False) -> jnp.ndarray:

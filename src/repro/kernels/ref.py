@@ -30,6 +30,16 @@ def pack_u32(mask: jnp.ndarray) -> jnp.ndarray:
     return (m * weights).sum(axis=-1, dtype=jnp.uint32)
 
 
+def pack_bitmajor(keep: jnp.ndarray) -> jnp.ndarray:
+    """bool[32, W] bit-major mask -> u32[1, W] packed words, inside a
+    Pallas kernel.  Mosaic has no unsigned reductions, so the sum runs in
+    int32 and is bitcast back: the 32 shifted bits of a word are disjoint,
+    so their sum is their OR (bit 31 lands exactly on the sign bit)."""
+    bitpos = jax.lax.broadcasted_iota(jnp.int32, keep.shape, 0)
+    words = (keep.astype(jnp.int32) << bitpos).sum(axis=0, keepdims=True)
+    return jax.lax.bitcast_convert_type(words, jnp.uint32)
+
+
 def compare(col: jnp.ndarray, value, opcode: int) -> jnp.ndarray:
     if opcode == LT:
         return col < value

@@ -35,10 +35,11 @@ def main():
     from ..ckpt import CheckpointManager
     from ..sharding import named_sharding, use_mesh
     from ..train import make_train_step, opt_state_pspecs
+    from .mesh import make_mesh
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     d, m = (int(x) for x in args.mesh.split("x"))
-    mesh = jax.make_mesh((d, m), ("data", "model"))
+    mesh = make_mesh((d, m), ("data", "model"))
 
     meta = make_corpus_metadata(50_000)
     ds = PredicateFilteredDataset(meta, default_quality_filter(),

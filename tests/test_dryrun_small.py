@@ -27,10 +27,11 @@ SCRIPT = textwrap.dedent("""
     from repro.models.config import ShapeCell
     from repro.sharding import use_mesh
     from repro.launch.dryrun import build_step
+    from repro.launch.mesh import make_mesh
     from repro.launch.roofline import collective_bytes
 
     out = {}
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+    mesh = make_mesh((4, 4), ("data", "model"))
 
     # 1) lower + compile tiny cells on the mesh (dense + moe + ssm)
     for arch in ("granite-3-8b", "qwen3-moe-30b-a3b", "zamba2-1.2b"):
@@ -67,7 +68,7 @@ SCRIPT = textwrap.dedent("""
     params = api.init(cfg, jax.random.PRNGKey(0))
     tmp = tempfile.mkdtemp()
     save_pytree({"params": params}, tmp, 1)
-    mesh2 = jax.make_mesh((2, 2), ("data", "model"))
+    mesh2 = make_mesh((2, 2), ("data", "model"))
     with use_mesh(mesh2):
         shardings = jax.tree.map(
             lambda sp: NamedSharding(mesh2, sp), api.pspecs(cfg, mesh2),
@@ -81,7 +82,7 @@ SCRIPT = textwrap.dedent("""
     # 4) compressed all-reduce mean over a pod axis
     from repro.train.compress import compressed_allreduce_mean
     from jax.experimental.shard_map import shard_map
-    pmesh = jax.make_mesh((4, 4), ("pod", "data"))
+    pmesh = make_mesh((4, 4), ("pod", "data"))
     g = jax.random.normal(jax.random.PRNGKey(2), (4, 128), jnp.float32)
     want = g.mean(axis=0, keepdims=True)
     got = shard_map(lambda x: compressed_allreduce_mean(x, "pod"),

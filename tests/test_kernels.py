@@ -1,4 +1,6 @@
 """Pallas kernel sweeps vs the pure-jnp ref oracles (interpret mode)."""
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -232,3 +234,46 @@ def test_dict_lookup_multi_matches_single():
             kref.popcount_ref(jnp.asarray(bits[j])).astype(jnp.int32),
             mask, interpret=True))
         np.testing.assert_array_equal(multi[j], single)
+
+
+@pytest.mark.parametrize("kernel", ["predicate", "dict_lookup"])
+def test_multi_kernels_split_stacks_past_prefetch_budget(kernel, monkeypatch):
+    """A stack whose popcounts overflow the SMEM budget runs in whole-query
+    chunks and still equals one single-query call per query."""
+    from repro.kernels.dict_lookup import (dict_lookup_scan,
+                                           dict_lookup_scan_multi)
+    # the module, not the function ``repro.kernels`` re-exports by its name
+    ps = importlib.import_module("repro.kernels.predicate_scan")
+    rng = np.random.default_rng(11)
+    q, n, b, dict_n = 5, 3, 256, 40
+    w = b // 32
+    col = rng.integers(0, dict_n, size=(n, b)).astype(np.float32)
+    col_bm = jnp.asarray(col.reshape(n, w, 32).transpose(0, 2, 1))
+    bits = rng.integers(0, 2 ** 32, size=(q, n, w), dtype=np.uint32)
+    bits[2, 1] = 0
+    mask = jnp.asarray(_pack_mask(rng.random(dict_n) < 0.3))
+    val = jnp.asarray([17.0], dtype=jnp.float32)
+    if kernel == "predicate":
+        def multi(bb, pp):
+            return ps.predicate_scan_multi(col_bm, bb, pp, val, 0,
+                                           interpret=True)
+
+        def single(bb, pp):
+            return ps.predicate_scan(col_bm, bb, pp, val, 0, interpret=True)
+    else:
+        def multi(bb, pp):
+            return dict_lookup_scan_multi(col_bm, bb, pp, mask,
+                                          interpret=True)
+
+        def single(bb, pp):
+            return dict_lookup_scan(col_bm, bb, pp, mask, interpret=True)
+    # room for two queries' popcounts (and the lookup's 2 mask words)
+    monkeypatch.setattr(ps, "MAX_PREFETCH_WORDS", 2 * n + 2)
+    assert ps.query_chunks(q * n, n) == [(0, 6), (6, 12), (12, 15)]
+    flat = jnp.asarray(bits.reshape(q * n, w))
+    got = np.asarray(multi(flat, kref.popcount_ref(flat).astype(jnp.int32)))
+    for j in range(q):
+        one = jnp.asarray(bits[j])
+        want = np.asarray(single(one,
+                                 kref.popcount_ref(one).astype(jnp.int32)))
+        np.testing.assert_array_equal(got[j * n:(j + 1) * n], want)
