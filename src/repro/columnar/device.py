@@ -75,6 +75,7 @@ values (masked by the zero bitmaps), keeping results exact.
 from __future__ import annotations
 
 import functools
+import time
 from collections import OrderedDict
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
@@ -456,6 +457,11 @@ def _tape_forward(ops, meta, result, n_slots, prune, skip, pallas, interpret,
            else jnp.zeros((0,), dtype=jnp.int32))
     return bits[result], rec, blk, prn, out
 
+#: eager bookkeeping ops of one zone-pruned compare atom through
+#: ``DeviceTapeBackend.apply_atom``: 8 cost counters, 1 verdict upload,
+#: 2 feedback popcounts
+ZONED_ATOM_BOOKKEEPING = 11
+
 #: bound on a backend's undrained observation log — sessions drain it every
 #: batch; standalone benchmark loops must not grow it without bound
 _OP_LOG_CAP = 4096
@@ -473,6 +479,37 @@ class DeviceTapeBackend(SetBackend):
     kernels:   "jax" = pure-jnp ops fused by XLA; "pallas" = the Pallas
                kernels (interpret mode off-TPU, see
                :func:`repro.kernels.ops.interpret_mode`)
+
+    Dispatch counters (lifetime, listed in
+    :data:`~repro.columnar.trace.BACKEND_COUNTERS`).  A *launch* is one
+    call into JAX that issues device work: a jitted program, or one eager
+    ``jnp`` operation or transfer (which may itself run more than one XLA
+    executable).
+
+    ``kernel_launches`` / ``kernel_host_s``
+        the jitted atom, lookup, multi, lookup_multi and chain prims and
+        whole-tape programs; the seconds include binding the atom's column
+        and lookup mask
+    ``setop_launches`` / ``setop_host_s``
+        the setop, inter_multi and union prims and the OR of
+        :meth:`extend_set`; ``kernel_launches + setop_launches ==
+        device_dispatches``
+    ``bookkeeping_launches`` / ``bookkeeping_host_s``
+        every other eager device op: the cost counters of
+        :meth:`_account`, the feedback popcounts of :meth:`_fb_queue`, the
+        stack and unstack of the multi paths, zone-verdict and lookup-mask
+        uploads, ``full``/``empty`` set uploads, and :meth:`materialize`'s
+        counter stacks and result flattening.  A zone-pruned compare atom
+        through :meth:`apply_atom` issues :data:`ZONED_ATOM_BOOKKEEPING`.
+    ``zone_host_s``
+        host seconds in :meth:`_zone_mask`
+
+    Host seconds accrue only in the calls the executors make while
+    dispatching (``apply_atom*``, the set ops, ``inter_multi``,
+    ``run_tape``), at one ``perf_counter`` pair per timed stretch, and the
+    four never overlap; launches count wherever they happen (the OR of
+    :meth:`extend_set` during upload, :meth:`materialize` during sync).
+    No counter adds a sync.
     """
 
     def __init__(self, table: Table, block: int = 8192,
@@ -499,6 +536,14 @@ class DeviceTapeBackend(SetBackend):
         self.host_fallbacks = 0
         self.device_dispatches = 0
         self.uploaded_bytes = 0       # host->device column traffic
+        # the dispatch split (see the class docstring)
+        self.kernel_launches = 0
+        self.kernel_host_s = 0.0
+        self.setop_launches = 0
+        self.setop_host_s = 0.0
+        self.bookkeeping_launches = 0
+        self.bookkeeping_host_s = 0.0
+        self.zone_host_s = 0.0
         self.last_tape: Optional[PlanTape] = None
         self._jcols: Dict[str, "object"] = {}
         self._full: Optional[_DevSet] = None
@@ -534,6 +579,32 @@ class DeviceTapeBackend(SetBackend):
         pin each kind's block axis to the 1-D shard mesh."""
         import jax.numpy as jnp
         return jnp.asarray(arr)
+
+    def _lap(self, counter: str, since: float) -> float:
+        """Add the host seconds since ``since`` to the ``counter``
+        attribute; returns now, the start of the next timed stretch."""
+        now = time.perf_counter()
+        setattr(self, counter, getattr(self, counter) + now - since)
+        return now
+
+    def _eager(self, fn, *args, **kwargs):
+        """One eager bookkeeping op on the dispatch path, counted and
+        timed (``jnp.asarray`` of a verdict row or lookup mask, the stack
+        of a multi path)."""
+        t0 = time.perf_counter()
+        out = fn(*args, **kwargs)
+        self.bookkeeping_launches += 1
+        self._lap("bookkeeping_host_s", t0)
+        return out
+
+    def _unstack(self, bits, pops, q: int) -> List[_DevSet]:
+        """Split stacked ``[q, ...]`` results into q device sets (two
+        eager slices per set, counted and timed as bookkeeping)."""
+        t0 = time.perf_counter()
+        out = [_DevSet(bits[j], pops[j]) for j in range(q)]
+        self.bookkeeping_launches += 2 * q
+        self._lap("bookkeeping_host_s", t0)
+        return out
 
     def _col_bitmajor(self, name: str):
         """Column as bit-major f32[N, 32, W] device blocks (None if the
@@ -589,34 +660,38 @@ class DeviceTapeBackend(SetBackend):
         which evaluates in float64 (the JaxBlockBackend precedent)."""
         if self._zones is None:
             return None
-        real = (self.n + self.block - 1) // self.block
-        out = None
-        any_verdict = False
-        for a in atoms:
-            v = self._zones.verdicts(a, exact=exact)
-            if v is None:
-                v = np.full(real, ZONE_MAYBE, dtype=np.int8)
-            elif len(v) != real:
-                return None   # zone map describes a different snapshot
-            else:
-                any_verdict = True
-            if out is None:
-                out = v.astype(np.int32)
-                continue
-            if conj:
-                none = (out == ZONE_NONE) | (v == ZONE_NONE)
-                alls = (out == ZONE_ALL) & (v == ZONE_ALL)
-            else:
-                alls = (out == ZONE_ALL) | (v == ZONE_ALL)
-                none = (out == ZONE_NONE) & (v == ZONE_NONE)
-            out = np.full(real, ZONE_MAYBE, dtype=np.int32)
-            out[alls] = ZONE_ALL
-            out[none] = ZONE_NONE
-        if not any_verdict or (out == ZONE_MAYBE).all():
-            return None
-        pad = np.full(self.nblocks, ZONE_NONE, dtype=np.int32)
-        pad[:real] = out
-        return pad
+        t0 = time.perf_counter()
+        try:
+            real = (self.n + self.block - 1) // self.block
+            out = None
+            any_verdict = False
+            for a in atoms:
+                v = self._zones.verdicts(a, exact=exact)
+                if v is None:
+                    v = np.full(real, ZONE_MAYBE, dtype=np.int8)
+                elif len(v) != real:
+                    return None   # zone map describes a different snapshot
+                else:
+                    any_verdict = True
+                if out is None:
+                    out = v.astype(np.int32)
+                    continue
+                if conj:
+                    none = (out == ZONE_NONE) | (v == ZONE_NONE)
+                    alls = (out == ZONE_ALL) & (v == ZONE_ALL)
+                else:
+                    alls = (out == ZONE_ALL) | (v == ZONE_ALL)
+                    none = (out == ZONE_NONE) & (v == ZONE_NONE)
+                out = np.full(real, ZONE_MAYBE, dtype=np.int32)
+                out[alls] = ZONE_ALL
+                out[none] = ZONE_NONE
+            if not any_verdict or (out == ZONE_MAYBE).all():
+                return None
+            pad = np.full(self.nblocks, ZONE_NONE, dtype=np.int32)
+            pad[:real] = out
+            return pad
+        finally:
+            self._lap("zone_host_s", t0)
 
     def refresh(self) -> int:
         """Grow the backend after a pure table *append*: device-resident
@@ -666,18 +741,25 @@ class DeviceTapeBackend(SetBackend):
         bits = s.bits
         if bits.shape[0] < self.nblocks:
             bits = jnp.pad(bits, ((0, self.nblocks - bits.shape[0]), (0, 0)))
+            self.bookkeeping_launches += 1
         self.device_dispatches += 1
+        self.setop_launches += 1
         bits = bits | self._place(words.reshape(self.nblocks, self.wpb),
                                   "bits")
+        self.bookkeeping_launches += 2      # the upload, the popcount
         return _DevSet(bits, ref.popcount_ref(bits))
 
     def _from_flat(self, words: np.ndarray) -> _DevSet:
         """Host flat packed words -> device blocked set."""
         from ..kernels import ref
+        t0 = time.perf_counter()
         padded = np.zeros(self.nblocks * self.wpb, dtype=np.uint32)
         padded[: n_words(self.n)] = words
         bits = self._place(padded.reshape(self.nblocks, self.wpb), "bits")
-        return _DevSet(bits, ref.popcount_ref(bits))
+        out = _DevSet(bits, ref.popcount_ref(bits))
+        self.bookkeeping_launches += 2      # the upload, the popcount
+        self._lap("bookkeeping_host_s", t0)
+        return out
 
     def _flat_device(self, d: _DevSet):
         """Blocked device bitmap -> flat device words (real length)."""
@@ -697,19 +779,25 @@ class DeviceTapeBackend(SetBackend):
 
     def empty(self) -> _DevSet:
         if self._empty is None:
+            t0 = time.perf_counter()
             bits = self._place(np.zeros((self.nblocks, self.wpb),
                                         dtype=np.uint32), "bits")
             pops = self._place(np.zeros((self.nblocks,),
                                         dtype=np.int32), "pops")
             self._empty = _DevSet(bits, pops)
+            self.bookkeeping_launches += 2
+            self._lap("bookkeeping_host_s", t0)
         return self._empty
 
     def _setop(self, a: _DevSet, b: _DevSet, code: int) -> _DevSet:
+        t0 = time.perf_counter()
         self.stats.setops += 1
         self.device_dispatches += 1
+        self.setop_launches += 1
         out, pops = _jitted_prims()["setop"](a.bits, b.bits, setop=code,
                                              pallas=self.pallas,
                                              interpret=self.interpret)
+        self._lap("setop_host_s", t0)
         return _DevSet(out, pops)
 
     def inter(self, a, b):
@@ -734,11 +822,14 @@ class DeviceTapeBackend(SetBackend):
         if len(ds) == 1:
             return [self.inter(a, ds[0])]
         import jax.numpy as jnp
-        bits = jnp.stack([d.bits for d in ds])
+        bits = self._eager(jnp.stack, [d.bits for d in ds])
+        t0 = time.perf_counter()
         self.stats.setops += len(ds)
         self.device_dispatches += 1
+        self.setop_launches += 1
         out, pops = _jitted_prims()["inter_multi"](a.bits, bits)
-        return [_DevSet(out[j], pops[j]) for j in range(len(ds))]
+        self._lap("setop_host_s", t0)
+        return self._unstack(out, pops, len(ds))
 
     def _account(self, atoms: Sequence[Atom], pops, device: bool = True,
                  zone: Optional[np.ndarray] = None):
@@ -756,8 +847,12 @@ class DeviceTapeBackend(SetBackend):
         paper metric measures the plan, not the storage-level pruning, so
         plan-quality comparisons are unaffected (the JaxBlockBackend
         precedent).
+
+        Eager device ops (bookkeeping launches): 3 on the host path, 4
+        unpruned, 8 zone-pruned.
         """
         import jax.numpy as jnp
+        t0 = time.perf_counter()
         self.stats.atom_applications += len(atoms)
         self._pend_records.append(pops.sum())
         self._pend_k.append(len(atoms))
@@ -765,14 +860,19 @@ class DeviceTapeBackend(SetBackend):
         if not device:
             self._pend_blocks.append(jnp.int32(0))
             self._pend_pruned.append(jnp.int32(0))
+            launches = 3
         elif zone is None:
             self._pend_blocks.append((pops > 0).sum())
             self._pend_pruned.append(jnp.int32(0))
+            launches = 4
         else:
             maybe = jnp.asarray(zone == ZONE_MAYBE)
             live = pops > 0
             self._pend_blocks.append((live & maybe).sum())
             self._pend_pruned.append((live & ~maybe).sum())
+            launches = 8
+        self.bookkeeping_launches += launches
+        self._lap("bookkeeping_host_s", t0)
 
     # -- realized-selectivity feedback (rides the existing syncs) --------------
     def _log_op(self, keys: Tuple, est: float, src: int, out: int) -> None:
@@ -784,14 +884,20 @@ class DeviceTapeBackend(SetBackend):
         if len(self.op_log) > _OP_LOG_CAP:
             del self.op_log[: len(self.op_log) - _OP_LOG_CAP]
 
-    def _fb_queue(self, atoms: Sequence[Atom], conj: bool, src, out) -> None:
-        """Queue one observation whose src/out popcounts are still device
-        scalars (or stacked ``i32[Q]`` vectors); they ride the bundled
-        transfer :meth:`materialize` already makes — no extra sync."""
+    def _fb_queue(self, atoms: Sequence[Atom], conj: bool, src_pops,
+                  out_pops) -> None:
+        """Queue one observation from the source and output per-block
+        popcounts (``i32[N]``, or stacked ``i32[Q, N]``): their totals stay
+        device scalars (or ``i32[Q]`` vectors) and ride the bundled
+        transfer :meth:`materialize` already makes — no extra sync.  Two
+        eager device ops (bookkeeping launches)."""
+        t0 = time.perf_counter()
         est = group_selectivity([a.selectivity for a in atoms], conj)
         self._fb_meta.append((tuple(atom_key(a) for a in atoms), est))
-        self._fb_src.append(src)
-        self._fb_out.append(out)
+        self._fb_src.append(src_pops.sum(axis=-1))
+        self._fb_out.append(out_pops.sum(axis=-1))
+        self.bookkeeping_launches += 2
+        self._lap("bookkeeping_host_s", t0)
 
     def drain_op_log(self) -> List[Tuple]:
         """Pop accumulated ``(keys, est, src, out)`` observations."""
@@ -868,18 +974,26 @@ class DeviceTapeBackend(SetBackend):
         return None, None
 
     def apply_atom(self, atom: Atom, d: _DevSet) -> _DevSet:
+        """One atom on one device set: one kernel launch and, for a
+        zone-pruned compare atom, :data:`ZONED_ATOM_BOOKKEEPING` eager
+        bookkeeping ops (the cost counters, the verdict upload, the two
+        feedback popcounts); a lookup atom adds its mask upload."""
         import jax.numpy as jnp
+        t0 = time.perf_counter()
         col, lmask = self._bind_atom(atom)
+        self._lap("kernel_host_s", t0)
         zone = self._zone_mask([atom]) if col is not None else None
         self._account([atom], d.pops, device=col is not None, zone=zone)
         if col is None:
             return self._apply_host(atom, [d], d)[0]
-        zj = None if zone is None else jnp.asarray(zone)
+        zj = None if zone is None else self._eager(jnp.asarray, zone)
+        lj = None if lmask is None else self._eager(jnp.asarray, lmask)
         skip = zone is not None and not (zone == ZONE_MAYBE).any()
+        t0 = time.perf_counter()
         self.device_dispatches += 1
-        if lmask is not None:
-            out, pops = _jitted_prims()["lookup"](col, d.bits, d.pops,
-                                                  jnp.asarray(lmask),
+        self.kernel_launches += 1
+        if lj is not None:
+            out, pops = _jitted_prims()["lookup"](col, d.bits, d.pops, lj,
                                                   zone=zj, skip=skip,
                                                   pallas=self.pallas,
                                                   interpret=self.interpret)
@@ -890,7 +1004,8 @@ class DeviceTapeBackend(SetBackend):
                                                 opcode=_CMP_OPCODE[atom.op],
                                                 pallas=self.pallas,
                                                 interpret=self.interpret)
-        self._fb_queue([atom], True, d.pops.sum(), pops.sum())
+        self._lap("kernel_host_s", t0)
+        self._fb_queue([atom], True, d.pops, pops)
         return _DevSet(out, pops)
 
     def apply_atom_multi(self, atom: Atom, ds: Sequence[_DevSet]
@@ -899,25 +1014,32 @@ class DeviceTapeBackend(SetBackend):
         if len(ds) == 1:
             return [self.apply_atom(atom, ds[0])]
         import jax.numpy as jnp
-        bits = jnp.stack([d.bits for d in ds])
-        pops = jnp.stack([d.pops for d in ds])
+        bits = self._eager(jnp.stack, [d.bits for d in ds])
+        pops = self._eager(jnp.stack, [d.pops for d in ds])
         # one reduce dispatch (not Q-1 setops): the union only feeds the
         # fallback path and cost accounting, mirroring the block engines'
         # uncounted host union
+        t0 = time.perf_counter()
         self.device_dispatches += 1
+        self.setop_launches += 1
         ubits, upops = _jitted_prims()["union"](bits, pops)
         union = _DevSet(ubits, upops)
+        t0 = self._lap("setop_host_s", t0)
         col, lmask = self._bind_atom(atom)
+        self._lap("kernel_host_s", t0)
         zone = self._zone_mask([atom]) if col is not None else None
         self._account([atom], union.pops, device=col is not None, zone=zone)
         if col is None:
             return self._apply_host(atom, ds, union)
-        zj = None if zone is None else jnp.asarray(zone)
+        zj = None if zone is None else self._eager(jnp.asarray, zone)
+        lj = None if lmask is None else self._eager(jnp.asarray, lmask)
         skip = zone is not None and not (zone == ZONE_MAYBE).any()
+        t0 = time.perf_counter()
         self.device_dispatches += 1
-        if lmask is not None:
+        self.kernel_launches += 1
+        if lj is not None:
             out, opops = _jitted_prims()["lookup_multi"](
-                col, bits, pops, jnp.asarray(lmask), zone=zj, skip=skip,
+                col, bits, pops, lj, zone=zj, skip=skip,
                 pallas=self.pallas, interpret=self.interpret)
         else:
             out, opops = _jitted_prims()["multi"](col, bits, pops,
@@ -926,9 +1048,9 @@ class DeviceTapeBackend(SetBackend):
                                                   opcode=_CMP_OPCODE[atom.op],
                                                   pallas=self.pallas,
                                                   interpret=self.interpret)
-        self._fb_queue([atom], True, pops.sum(axis=-1),
-                       opops.sum(axis=-1))
-        return [_DevSet(out[j], opops[j]) for j in range(len(ds))]
+        self._lap("kernel_host_s", t0)
+        self._fb_queue([atom], True, pops, opops)
+        return self._unstack(out, opops, len(ds))
 
     # -- the single end-of-query (or end-of-batch) host sync -------------------
     def materialize(self, sets: Sequence[_DevSet]) -> List[np.ndarray]:
@@ -946,6 +1068,8 @@ class DeviceTapeBackend(SetBackend):
             rec = jnp.zeros((0,), dtype=jnp.int32)
             blk = jnp.zeros((0,), dtype=jnp.int32)
             prn = jnp.zeros((0,), dtype=jnp.int32)
+        # a reshape and a slice per result, the three counter vectors
+        self.bookkeeping_launches += 2 * len(flats) + 3
         self.host_syncs += 1
         flats, rec, blk, prn, fsrc, fout = jax.device_get(
             (flats, rec, blk, prn, self._fb_src, self._fb_out))
@@ -1087,9 +1211,10 @@ class DeviceTapeBackend(SetBackend):
                 any_decided = True
             rows.append(z)
         if not rows:
-            return self._place(np.zeros((0, self.nblocks), dtype=np.int32),
+            return self._eager(self._place, np.zeros((0, self.nblocks),
+                                                     dtype=np.int32),
                                "zmask"), False
-        return self._place(np.stack(rows).astype(np.int32),
+        return self._eager(self._place, np.stack(rows).astype(np.int32),
                            "zmask"), any_decided
 
     def _tape_program(self, tape: PlanTape, meta, skip: bool = False):
@@ -1133,12 +1258,15 @@ class DeviceTapeBackend(SetBackend):
         run as ONE jitted dispatch and ONE host sync.  Tapes with host-
         fallback ops (opaque UDF atoms, unrewritten non-numeric columns)
         run op-by-op with device slots, syncing only at each fallback and
-        at the end.
+        at the end.  The whole-tape path's own sync (``device_get``) is
+        in no dispatch counter's host seconds.
         """
         import jax.numpy as jnp
         _faults.trip("device.dispatch", backend=self, where="run_tape")
         self.last_tape = tape
+        t0 = time.perf_counter()
         cols, values, lmasks, meta, device_ok = self._tape_bindings(tape)
+        self._lap("kernel_host_s", t0)
         atoms = tape.tree.atoms
         full = self.full()
         if all(device_ok):
@@ -1151,13 +1279,15 @@ class DeviceTapeBackend(SetBackend):
             self.stats.setops += sum(1 for op in tape.ops
                                      if op.kind == SETOP)
             zmasks, any_decided = self._tape_zone_masks(tape)
+            vj = self._eager(jnp.asarray, values, dtype=jnp.float32)
+            lj = self._eager(jnp.asarray, lmasks)
+            t0 = time.perf_counter()
             prog = self._tape_program(tape, tuple(meta), skip=any_decided)
             self.device_dispatches += 1
-            res, rec, blk, prn, outs = prog(tuple(cols),
-                                            jnp.asarray(values,
-                                                        dtype=jnp.float32),
-                                            jnp.asarray(lmasks), zmasks,
+            self.kernel_launches += 1
+            res, rec, blk, prn, outs = prog(tuple(cols), vj, lj, zmasks,
                                             full.bits, full.pops)
+            self._lap("kernel_host_s", t0)
             import jax
             self.host_syncs += 1
             res, rec, blk, prn, outs = jax.device_get(
@@ -1203,35 +1333,45 @@ class DeviceTapeBackend(SetBackend):
                     grp = [atoms[a] for a in op.aids]
                     zone = self._zone_mask(grp, conj=op.conj)
                     self._account(grp, src.pops, zone=zone)
-                    zj = None if zone is None else jnp.asarray(zone)
+                    zj = (None if zone is None
+                          else self._eager(jnp.asarray, zone))
                     skip = (zone is not None
                             and not (zone == ZONE_MAYBE).any())
+                    t0 = time.perf_counter()
                     cols = [self._col_bitmajor(atoms[a].column)
                             for a in op.aids]
+                    self._lap("kernel_host_s", t0)
+                    if opcodes[0] == IN_OPCODE:
+                        args = (cols[0], src.bits, src.pops,
+                                self._eager(jnp.asarray, lmasks[vixs[0]]))
+                    elif op.kind == ATOM:
+                        args = (cols[0], src.bits, src.pops,
+                                float(atoms[op.aids[0]].value))
+                    else:
+                        args = (self._eager(jnp.stack, cols, axis=1),
+                                src.bits, src.pops,
+                                self._eager(jnp.asarray,
+                                            [float(atoms[a].value)
+                                             for a in op.aids],
+                                            dtype=jnp.float32))
+                    t0 = time.perf_counter()
                     self.device_dispatches += 1
+                    self.kernel_launches += 1
                     if opcodes[0] == IN_OPCODE:
                         out, pops = prims["lookup"](
-                            cols[0], src.bits, src.pops,
-                            jnp.asarray(lmasks[vixs[0]]), zone=zj,
-                            skip=skip, pallas=self.pallas,
+                            *args, zone=zj, skip=skip, pallas=self.pallas,
                             interpret=self.interpret)
                     elif op.kind == ATOM:
                         out, pops = prims["atom"](
-                            cols[0], src.bits, src.pops,
-                            float(atoms[op.aids[0]].value), zone=zj,
-                            skip=skip, opcode=opcodes[0],
+                            *args, zone=zj, skip=skip, opcode=opcodes[0],
                             pallas=self.pallas, interpret=self.interpret)
                     else:
-                        stack = jnp.stack(cols, axis=1)
-                        vals = jnp.asarray(
-                            [float(atoms[a].value) for a in op.aids],
-                            dtype=jnp.float32)
                         out, pops = prims["chain"](
-                            stack, src.bits, src.pops, vals, zone=zj,
-                            skip=skip, opcodes=opcodes, conj=op.conj,
-                            pallas=self.pallas, interpret=self.interpret)
-                    self._fb_queue(grp, op.conj, src.pops.sum(),
-                                   pops.sum())
+                            *args, zone=zj, skip=skip, opcodes=opcodes,
+                            conj=op.conj, pallas=self.pallas,
+                            interpret=self.interpret)
+                    self._lap("kernel_host_s", t0)
+                    self._fb_queue(grp, op.conj, src.pops, pops)
                     s = _DevSet(out, pops)
             slots[op.dst] = s
         return self.materialize([slots[tape.result]])[0]

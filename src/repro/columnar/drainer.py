@@ -34,6 +34,8 @@ import time
 from dataclasses import dataclass
 from typing import List, Optional, Tuple
 
+from .trace import null_span
+
 #: admission lanes, in drain order (interactive work resolves first
 #: within a combined batch)
 LANES: Tuple[str, str] = ("interactive", "bulk")
@@ -131,6 +133,12 @@ class BackgroundDrainer:
     admission lock and calls back into ``session._drain_lanes`` with the
     lock *released* (drains execute queries; holding the admission lock
     across one would stall every ``submit``).
+
+    With the session's tracer on, every wait is a span: ``drainer.idle``
+    (nothing pending: the device has no work), ``drainer.deadline_wait``
+    (work pending, the policy waiting out a lane's deadline) and
+    ``drainer.deadline_drain`` (the drain itself, parent of the session's
+    ``stream.*`` spans).
     """
 
     def __init__(self, session, policy: DrainPolicy):
@@ -204,27 +212,26 @@ class BackgroundDrainer:
     def _loop(self) -> None:
         cond = self._session._admit
         while True:
+            tr = self._session.tracer
+            sp = tr.span if tr is not None else null_span
             with cond:
                 if self._stop:
                     return
                 now = time.perf_counter()
                 deadline = self._deadline_locked(now)
                 if deadline is None:
-                    cond.wait()         # submit()/stop() notify
+                    with sp("drainer.idle"):
+                        cond.wait()         # submit()/stop() notify
                     continue
                 if deadline > now:
-                    cond.wait(deadline - now)
+                    with sp("drainer.deadline_wait"):
+                        cond.wait(deadline - now)
                     continue
                 lanes = self._due_lanes_locked(now)
                 self.wakeups += 1
             if lanes:
                 self.deadline_drains += 1
-                tr = getattr(self._session, "tracer", None)
-                if tr is not None:
-                    # deadline drains run on this daemon thread; the span
-                    # parents the session's stream.drain/batch.* spans
-                    with tr.span("drainer.deadline_drain",
-                                 lanes=",".join(lanes)):
-                        self._session._drain_lanes(lanes)
-                else:
+                # deadline drains run on this daemon thread; the span
+                # parents the session's stream.*/batch.* spans
+                with sp("drainer.deadline_drain", lanes=",".join(lanes)):
                     self._session._drain_lanes(lanes)

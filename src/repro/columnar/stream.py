@@ -85,7 +85,8 @@ from .config import UNSET, ExecConfig, config_from_kwargs
 from .drainer import LANES, BackgroundDrainer, DrainPolicy, LatencyWindow
 from .multiquery import BatchResult, BatchStats, QuerySession
 from .table import Table
-from .trace import ExplainReport, format_tree, null_span, report_from_batch
+from .trace import (NULL_SPAN, ExplainReport, format_tree, null_span,
+                    report_from_batch)
 
 
 class StreamClosed(RuntimeError):
@@ -552,11 +553,16 @@ class StreamSession:
             self._admit.wait(0.05)
         self._check_open_locked()
 
+    def _span(self, name: str, **attrs):
+        tr = self.tracer
+        return tr.span(name, **attrs) if tr is not None else null_span(name)
+
     def append(self, rows: Dict) -> int:
         """Interleave an append with admission: lands in the table as a
         block-aligned delta (see :meth:`Table.append`); queries draining
-        *after* this call see the rows (snapshot-at-drain)."""
-        with self._drain_lock:
+        *after* this call see the rows (snapshot-at-drain).  Traced as one
+        ``stream.append`` span, its wait for the drain lock included."""
+        with self._span("stream.append"), self._drain_lock:
             with self._admit:
                 self._check_open_locked()
             start = self.table.append(rows)
@@ -573,8 +579,9 @@ class StreamSession:
         mask applies at materialize time.  When ``auto_compact`` is set
         and the dead fraction crosses it, the table compacts (the
         version-bumping, cache-invalidating path).  Returns the number of
-        rows newly tombstoned."""
-        with self._drain_lock:
+        rows newly tombstoned.  Traced as one ``stream.delete`` span, its
+        wait for the drain lock included."""
+        with self._span("stream.delete"), self._drain_lock:
             with self._admit:
                 self._check_open_locked()
             new = self.table.delete(rows)
@@ -618,6 +625,14 @@ class StreamSession:
 
     def _drain_lanes(self, lanes: Tuple[str, ...]
                      ) -> Optional[BatchResult]:
+        """Swap the due lanes out and drain them as one batch.  Traced as
+        ``stream.lock_wait`` (this drain's wait for ``_drain_lock``), one
+        ``stream.queued`` per request (admission to swap-out, keyed by the
+        future's id), ``stream.drain`` (the batch's execution) and
+        ``stream.resolve`` (commit, snapshot stamp, explain retention,
+        futures resolved, publication)."""
+        tr = self.tracer
+        t_wait = time.perf_counter() if tr is not None else 0.0
         with self._drain_lock:
             with self._admit:
                 batch: List[_Pending] = []
@@ -628,68 +643,79 @@ class StreamSession:
                         self._lanes[lane] = []
                 if not batch:
                     return None
+                t_swap = time.perf_counter()
                 # starvation gauge: age of the oldest bulk admit this
                 # drain is leaving behind (0 when bulk drained or empty)
                 left = self._lanes["bulk"]
                 self.stats.bulk_starved_s = (
-                    time.perf_counter() - left[0].t_admit if left else 0.0)
+                    t_swap - left[0].t_admit if left else 0.0)
                 self._admit.notify_all()    # backpressure waiters: space
-            tr = self.tracer
-            wait_ms = (time.perf_counter()
-                       - min(p.t_admit for p in batch)) * 1000.0
-            drain_span = (tr.span("stream.drain", queries=len(batch),
-                                  lanes=",".join(lanes),
-                                  queue_wait_ms=round(wait_ms, 3))
-                          if tr is not None else null_span("stream.drain"))
+            drain_span = NULL_SPAN
+            if tr is not None:
+                tr.record("stream.lock_wait", t_wait, t_swap)
+                for p in batch:
+                    tr.record("stream.queued", p.t_admit, t_swap,
+                              id=p.fut.id, lane=p.fut.lane)
+                ids = [p.fut.id for p in batch]
+                drain_span = tr.span("stream.drain", queries=len(batch),
+                                     lanes=",".join(lanes),
+                                     ids=(min(ids), max(ids)))
             with drain_span:
                 outcomes, res = self._execute_resilient(
                     [p.query for p in batch])
-            # group commit: ONE fsync covers every mutation this batch's
-            # snapshot saw, before any future resolves — results handed
-            # to callers always describe crash-durable state
-            if self._durability is not None:
-                ms = self._durability.commit()
-                if ms is not None:
-                    self._observe_commit(ms)
-            # snapshot stamped under _drain_lock: append/delete also hold
-            # it, so n_records/live_words here are exactly what executed
-            n = self.table.n_records
-            lw = self.table.live_words()
-            lw = lw.copy() if lw is not None else None
-            # reports are retained BEFORE futures resolve, so a caller
-            # returning from result() can explain() immediately (no race
-            # against this drain thread)
-            if res is not None:
-                self._retain_explains(batch, res, n)
-            now = time.perf_counter()
-            latencies: List[Tuple[str, float]] = []
-            with self._admit:
-                ok = 0
-                for p, out in zip(batch, outcomes):
-                    if isinstance(out, BaseException):
-                        p.fut._fail(out)
-                        self.stats.failed += 1
-                    else:
-                        p.fut._resolve(out, n, lw)
-                        lat = (now - p.t_admit) * 1000.0
-                        self.stats.latency.add(lat)
-                        latencies.append((p.fut.lane, lat))
-                        ok += 1
-                if res is not None:
-                    self.stats.absorb(res.stats)
-                    self.last_result = res
-                else:
-                    # quarantine drains have no single BatchStats
-                    self.stats.batches += 1
-                    self.stats.completed += ok
-                    self.stats.max_batch = max(self.stats.max_batch,
-                                               len(batch))
-                self._last_drain_at = time.monotonic()
-            if self._durability is not None:
-                self._durability.maybe_snapshot()
-            if self.telemetry is not None:
-                self._publish_drain(latencies)
+            with self._span("stream.resolve"):
+                self._resolve_drained(batch, outcomes, res)
             return res
+
+    def _resolve_drained(self, batch: List[_Pending], outcomes: list,
+                         res: Optional[BatchResult]) -> None:
+        """Post-drain host work, caller holds ``_drain_lock``: group
+        commit, snapshot stamp, explain retention, futures, publication."""
+        # group commit: ONE fsync covers every mutation this batch's
+        # snapshot saw, before any future resolves — results handed
+        # to callers always describe crash-durable state
+        if self._durability is not None:
+            ms = self._durability.commit()
+            if ms is not None:
+                self._observe_commit(ms)
+        # snapshot stamped under _drain_lock: append/delete also hold
+        # it, so n_records/live_words here are exactly what executed
+        n = self.table.n_records
+        lw = self.table.live_words()
+        lw = lw.copy() if lw is not None else None
+        # reports are retained BEFORE futures resolve, so a caller
+        # returning from result() can explain() immediately (no race
+        # against this drain thread)
+        if res is not None:
+            self._retain_explains(batch, res, n)
+        now = time.perf_counter()
+        latencies: List[Tuple[str, float]] = []
+        with self._admit:
+            ok = 0
+            for p, out in zip(batch, outcomes):
+                if isinstance(out, BaseException):
+                    p.fut._fail(out)
+                    self.stats.failed += 1
+                else:
+                    p.fut._resolve(out, n, lw)
+                    lat = (now - p.t_admit) * 1000.0
+                    self.stats.latency.add(lat)
+                    latencies.append((p.fut.lane, lat))
+                    ok += 1
+            if res is not None:
+                self.stats.absorb(res.stats)
+                self.last_result = res
+            else:
+                # quarantine drains have no single BatchStats
+                self.stats.batches += 1
+                self.stats.completed += ok
+                self.stats.max_batch = max(self.stats.max_batch,
+                                           len(batch))
+            self._last_drain_at = time.monotonic()
+        if self._durability is not None:
+            self._durability.maybe_snapshot()
+        if self.telemetry is not None:
+            self._publish_drain(latencies)
 
     def _retain_explains(self, batch: List[_Pending], res: BatchResult,
                          n_records: int) -> None:

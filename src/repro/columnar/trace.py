@@ -3,12 +3,16 @@
 Spans are host wall-clock only (``time.perf_counter``), nestable via a
 thread-local stack, and land in a bounded ring buffer — a drained batch
 costs a handful of clock reads and deque appends, cheap enough to leave on
-in production (``bench_device.py --obs`` gates the overhead in CI).  The
-one rule that keeps tracing honest on the device engines: **a span never
-forces a sync**.  Spans bracket the host-side phases (plan, rewrite,
-upload, dispatch, the bundled materialize); every device-side number they
+in production.  The one rule that keeps tracing honest on the device
+engines: **a span never forces a sync**.  Spans bracket the host-side
+phases (queueing, lock waits, plan, rewrite, upload, dispatch, the bundled
+materialize, the post-drain resolve); every device-side number they
 annotate was already fetched by the transfer the query paid for anyway
 (the PR 6 feedback plumbing — see docs/architecture.md §8).
+
+Compiles are spans too: one process-wide JAX monitoring listener turns
+each backend-compile event into a ``jax.compile`` span (attribute
+``fun_name``) on the tracer whose span is open on the compiling thread.
 
 :func:`explain_analyze` joins the chosen plan with the realized per-op
 selectivities drained from the engine op log, zone pruning, cache hits,
@@ -18,6 +22,7 @@ from __future__ import annotations
 
 import threading
 import time
+import weakref
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
@@ -80,11 +85,12 @@ def null_span(name: str, **attrs: Any) -> _NullSpan:
 
 
 class _ActiveSpan:
-    __slots__ = ("_tracer", "_rec")
+    __slots__ = ("_tracer", "_rec", "_ann")
 
-    def __init__(self, tracer: "Tracer", rec: SpanRecord):
+    def __init__(self, tracer: "Tracer", rec: SpanRecord, ann=None):
         self._tracer = tracer
         self._rec = rec
+        self._ann = ann
 
     def set(self, **attrs) -> "_ActiveSpan":
         """Attach attributes mid-span (e.g. counts known only at exit)."""
@@ -92,12 +98,60 @@ class _ActiveSpan:
         return self
 
     def __enter__(self) -> "_ActiveSpan":
+        if self._ann is not None:
+            self._ann.__enter__()
         self._tracer._push(self._rec)
         return self
 
     def __exit__(self, *exc) -> bool:
         self._tracer._pop(self._rec)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         return False
+
+
+#: JAX's monitoring event for one backend compile (also emitted when the
+#: executable comes from the persistent compilation cache)
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+# every tracer, so the process-wide compile listener can find the enabled
+# one whose span is open on the compiling thread; _TRACERS_LOCK guards the
+# set and the one-time registration
+_TRACERS: "weakref.WeakSet[Tracer]" = weakref.WeakSet()
+_TRACERS_LOCK = threading.Lock()
+_listening = False
+
+
+def _listen_for_compiles() -> None:
+    """Register :func:`_on_jax_event` with JAX once per process (JAX keeps
+    its listeners for the life of the process)."""
+    global _listening
+    with _TRACERS_LOCK:
+        if _listening:
+            return
+        import jax
+        jax.monitoring.register_event_duration_secs_listener(_on_jax_event)
+        _listening = True
+
+
+def _on_jax_event(event: str, secs: float, **kwargs: Any) -> None:
+    """Record a ``jax.compile`` span ending now, ``secs`` long, on the
+    tracer with the most recently opened span on this thread; dropped when
+    no tracer has a span open here.  JAX calls this synchronously on the
+    thread that compiles."""
+    if event != COMPILE_EVENT:
+        return
+    end = time.perf_counter()
+    with _TRACERS_LOCK:
+        tracers = list(_TRACERS)
+    owner, top = None, None
+    for tr in tracers:
+        st = getattr(tr._tls, "stack", None)
+        if tr.enabled and st and (top is None or st[-1].t0 > top.t0):
+            owner, top = tr, st[-1]
+    if owner is not None:
+        owner.record("jax.compile", end - secs, end,
+                     fun_name=str(kwargs.get("fun_name", "")))
 
 
 class Tracer:
@@ -105,10 +159,12 @@ class Tracer:
 
     Thread-safe: each thread nests through its own stack (drainer threads
     and callers trace concurrently); completed spans append to one shared
-    ring under a lock.  ``profiler=True`` additionally opens a
-    ``jax.profiler`` trace context around :meth:`profile_span` sections
-    (the drain path), so spans line up with XLA's own timeline when a
-    profile is being captured — and costs nothing when one is not.
+    ring under a lock.  ``profiler=True`` also opens a
+    ``jax.profiler.TraceAnnotation`` of the same name around every span,
+    so a profile captured with ``jax.profiler`` shows the program's spans
+    on the device trace's own clock (spans given to :meth:`record` after
+    the fact are not annotated).  Outside a capture an annotation costs a
+    check in the profiler's C++ and records nothing.
     """
 
     def __init__(self, capacity: int = 4096, enabled: bool = True,
@@ -121,6 +177,8 @@ class Tracer:
         self._lock = threading.Lock()
         self._tls = threading.local()
         self._seq = 0
+        with _TRACERS_LOCK:
+            _TRACERS.add(self)
 
     # -- internals -------------------------------------------------------------
     def _stack(self) -> List[SpanRecord]:
@@ -153,12 +211,36 @@ class Tracer:
         current span.  Returns a shared no-op when disabled."""
         if not self.enabled:
             return _NULL_SPAN
+        if not _listening:
+            _listen_for_compiles()
         with self._lock:
             self._seq += 1
             seq = self._seq
+        ann = None
+        if self.profiler:
+            from jax.profiler import TraceAnnotation
+            ann = TraceAnnotation(name)
         return _ActiveSpan(self, SpanRecord(
             name=name, t0=0.0, seq=seq,
-            thread=threading.current_thread().name, attrs=dict(attrs)))
+            thread=threading.current_thread().name, attrs=dict(attrs)), ann)
+
+    def record(self, name: str, t0: float, t1: float, **attrs: Any) -> None:
+        """Record a finished span that began before this call (``t0``,
+        ``t1`` on the ``perf_counter`` clock): a request's queue time, a
+        compile.  It nests under the calling thread's innermost open span.
+        Dropped when disabled."""
+        if not self.enabled:
+            return
+        st = self._stack()
+        rec = SpanRecord(name=name, t0=t0, dur_ms=(t1 - t0) * 1000.0,
+                         depth=len(st),
+                         parent_seq=st[-1].seq if st else None,
+                         thread=threading.current_thread().name,
+                         attrs=dict(attrs))
+        with self._lock:
+            self._seq += 1
+            rec.seq = self._seq
+            self._ring.append(rec)
 
     def event(self, name: str, **attrs: Any) -> None:
         """Attach a point event to the innermost active span (dropped when
@@ -171,35 +253,6 @@ class Tracer:
         rec = st[-1]
         rec.events.append(
             (name, (time.perf_counter() - rec.t0) * 1000.0, dict(attrs)))
-
-    def profile_span(self, name: str, **attrs: Any):
-        """A span that also opens a ``jax.profiler`` trace annotation when
-        :attr:`profiler` is set (and jax is importable) — the bridge that
-        makes drains visible inside captured XLA profiles."""
-        if not self.enabled:
-            return _NULL_SPAN
-        sp = self.span(name, **attrs)
-        if not self.profiler:
-            return sp
-        try:
-            from jax.profiler import TraceAnnotation
-        except Exception:       # pragma: no cover - jax always present here
-            return sp
-        outer = TraceAnnotation(name)
-
-        class _Both:
-            def __enter__(self_b):
-                outer.__enter__()
-                return sp.__enter__()
-
-            def __exit__(self_b, *exc):
-                try:
-                    sp.__exit__(*exc)
-                finally:
-                    outer.__exit__(*exc)
-                return False
-
-        return _Both()
 
     def drain(self) -> List[SpanRecord]:
         """Pop every completed span (oldest first)."""
@@ -243,11 +296,15 @@ def resolve_tracer(setting: Any) -> Optional[Tracer]:
 # ---------------------------------------------------------------------------
 
 #: per-backend lifetime counters the report snapshots as per-query deltas
-#: (single source of names — the bench obs sections and §8 docs use it too)
+#: (single source of names — the bench obs sections and §8 docs use it too).
+#: The ``*_launches`` / ``*_host_s`` split of the device backends' dispatch
+#: is documented on :class:`~repro.columnar.device.DeviceTapeBackend`.
 BACKEND_COUNTERS: Tuple[str, ...] = (
     "host_syncs", "device_dispatches", "kernel_invocations",
     "host_fallbacks", "uploaded_bytes", "blocks_touched",
-    "records_touched", "blocks_pruned")
+    "records_touched", "blocks_pruned",
+    "kernel_launches", "kernel_host_s", "setop_launches", "setop_host_s",
+    "bookkeeping_launches", "bookkeeping_host_s", "zone_host_s")
 
 
 def backend_counters(backend: Any) -> Dict[str, float]:
@@ -484,6 +541,7 @@ def explain_analyze(query: Any, table: Any = None, *,
 
 __all__ = [
     "SpanRecord", "Tracer", "tracer", "resolve_tracer", "NULL_SPAN",
+    "COMPILE_EVENT",
     "null_span", "BACKEND_COUNTERS", "backend_counters", "OpObservation",
     "ExplainReport", "report_from_batch", "explain_analyze",
     "format_tree",

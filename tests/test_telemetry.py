@@ -265,8 +265,10 @@ def test_stream_health_explain_and_latency(forest):
     spans = tr.drain()
     names = {s.name for s in spans}
     assert {"stream.drain", "batch.execute", "batch.sync"} <= names
-    drain = next(s for s in spans if s.name == "stream.drain")
-    assert "queue_wait_ms" in drain.attrs
+    # each request's queue wait is its own stream.queued span, keyed by id
+    queued = [s for s in spans if s.name == "stream.queued"]
+    assert sorted(s.attrs["id"] for s in queued) == sorted(f.id for f in futs)
+    assert all(s.dur_ms >= 0 for s in queued)
 
 
 def test_explain_retention_bounded(forest):
