@@ -3,10 +3,13 @@
 Interleaves row appends with fixed-template query batches over one table
 (strings included, so dictionary merges run on every append) and compares
 
-* **stream** — one long-lived :class:`StreamSession` draining through the
-  device-resident lockstep tape executor (one bundled host sync per batch):
-  cached atom results splice in only appended rows, the device backend
-  re-uploads only dirty tail blocks, and the plan cache persists;
+* **stream** — one long-lived :class:`StreamSession` on its default config:
+  each query of a drain runs as one compiled whole-plan tape, one bundled
+  host sync per batch, the device backend re-uploads only dirty tail
+  blocks, and the plan cache persists.  Tapes keep no atom results between
+  drains, so the delta-reuse ratio reads 0 here; cached atom results that
+  splice in only appended rows belong to the lockstep executor
+  (``batched=True``);
 * **naive**  — a fresh ``QuerySession`` per round (the pre-ingest behavior:
   full column re-upload, full-table atom evaluation, cold plan cache).
 

@@ -68,11 +68,6 @@ class Gate:
         return 0
 
 
-def _per_query_syncs(batch: dict) -> float:
-    q = max(batch.get("queries", 1), 1)
-    return batch.get("tape_host_syncs_per_batch", -1) / q
-
-
 def check_device(gate: Gate, fresh: dict, base: dict, tol: float) -> None:
     single, bsingle = fresh.get("single", {}), base.get("single", {})
     batch, bbatch = fresh.get("batch", {}), base.get("batch", {})
@@ -97,10 +92,10 @@ def check_device(gate: Gate, fresh: dict, base: dict, tol: float) -> None:
     gate.check("single.host_fallbacks == 0",
                single.get("host_fallbacks", -1) == 0,
                f"fresh={single.get('host_fallbacks')}")
-    gate.check("batch.tape syncs per query",
-               _per_query_syncs(batch) == _per_query_syncs(bbatch),
-               f"fresh={_per_query_syncs(batch):g} "
-               f"baseline={_per_query_syncs(bbatch):g}")
+    # the tape batch launches every query's program, then ONE bundled sync
+    gate.check("batch.tape_host_syncs_per_batch == 1",
+               batch.get("tape_host_syncs_per_batch") == 1,
+               f"fresh={batch.get('tape_host_syncs_per_batch')}")
     gate.check("batch.tape_lockstep_host_syncs_per_batch",
                batch.get("tape_lockstep_host_syncs_per_batch")
                == bbatch.get("tape_lockstep_host_syncs_per_batch"),

@@ -206,7 +206,7 @@ def config_from_kwargs(config: Optional[ExecConfig],
     """Resolve ``config=`` vs legacy kwargs into one :class:`ExecConfig`.
 
     ``defaults`` is the entry point's base config (e.g. ``StreamSession``
-    defaults to ``engine='tape', batched=True``); legacy kwargs left at
+    defaults to ``engine='tape', planner='deepfish'``); legacy kwargs left at
     ``UNSET`` are dropped, explicitly passed ones warn once per name and
     override the base.  Passing both ``config=`` and any legacy kwarg is a
     :class:`ConfigError` — there is no precedence to guess.

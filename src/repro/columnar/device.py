@@ -1,17 +1,21 @@
 """Device-resident plan execution: compiled tapes + a device SetBackend.
 
 ``DeviceTapeBackend`` keeps every record bitmap a plan touches *on the
-device* and talks to the host exactly once per query.  It plays two roles:
+device* and talks to the host once per batch of queries.  It plays two
+roles:
 
-1. **Whole-tape executor** — :meth:`run_tape` takes a
-   :class:`~repro.core.tape.PlanTape` and runs it as ONE jitted device
-   program: a functional slot file of ``u32[N, W]`` bitmaps (plus per-block
-   popcounts for kernel-side dead-block skipping), ATOM ops lowered to the
-   fused compare∧bitmap kernel, CHAIN ops to ``fused_chain_scan``, SETOPs to
-   ``bitmap_setop`` — then a single ``device_get`` fetches the result bitmap
-   together with the per-step cost counters.  This is the
+1. **Whole-tape executor** — :meth:`run_tapes` takes a batch of
+   :class:`~repro.core.tape.PlanTape` objects and runs each as ONE jitted
+   device program: a functional slot file of ``u32[N, W]`` bitmaps (plus
+   per-block popcounts for kernel-side dead-block skipping), ATOM ops
+   lowered to the fused compare∧bitmap kernel, CHAIN ops to
+   ``fused_chain_scan``, SETOPs to ``bitmap_setop`` — then a single
+   ``device_get`` fetches every result bitmap together with the per-step
+   cost counters.  This is the
    dispatch-count-O(1), host-sync-count-1 path ``run_query(engine="tape")``
-   uses.
+   (:meth:`run_tape`, which compiles a program on the call) and the
+   sessions' batches use; a session's tape whose program is not compiled
+   yet runs op by op while :data:`_COMPILER` compiles it off the batch.
 
 2. **Device-resident SetBackend** — the generic
    :class:`~repro.core.sets.SetBackend` interface over device sets
@@ -45,12 +49,14 @@ Design note — slot allocation and the one-sync-per-query contract
 The tape compiler emits SSA ops and then linear-scan-allocates them onto a
 minimal physical slot set, so a tape's working set is a dense
 ``u32[S, N, W]`` slot file whose ``S`` is typically far below the op count
-(BestD's Delta bookkeeping is mostly dead-code-eliminated; survivors reuse
-recycled slots).  During execution nothing leaves the device: popcounts ride
+(BestD's Delta bookkeeping is mostly dead-code-eliminated and its repeated
+set ops merged; survivors reuse recycled slots).  During execution nothing
+leaves the device: popcounts ride
 along as ``i32[N]`` vectors (feeding the Pallas kernels' scalar-prefetch
 dead-block skip), per-step record/block counts accumulate into device
 vectors, and the final transfer bundles ``(result bitmap, counters)`` into
-one ``device_get`` — exactly one host sync per query.  String predicates
+one ``device_get`` — exactly one host sync per query, or per batch of tapes
+(:meth:`DeviceTapeBackend.run_tapes`).  String predicates
 over dictionary-encodable columns do NOT relax the contract: the planner
 entry points rewrite them into numeric comparisons over the columns' int32
 dictionary codes (``columnar.table.rewrite_string_atoms``), which this
@@ -74,8 +80,15 @@ values (masked by the zero bitmaps), keeping results exact.
 """
 from __future__ import annotations
 
+import atexit
+import contextlib
 import functools
+import queue
+import threading
 import time
+import traceback
+import warnings
+import weakref
 from collections import OrderedDict
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
@@ -110,21 +123,25 @@ class _DevSet(NamedTuple):
 # jitted per-op wrappers)
 # ---------------------------------------------------------------------------
 
-def _setop_impl(a, b, setop: int, pallas: bool, interpret: bool):
+def _setop_bits(a, b, setop: int):
+    """The pure-jnp set op on packed bitmaps, without popcounts."""
     import jax.numpy as jnp
+    if setop == OP_AND:
+        return a & b
+    if setop == OP_OR:
+        return a | b
+    if setop == OP_ANDNOT:
+        return a & jnp.bitwise_not(b)
+    raise ValueError(f"bad setop {setop}")  # pragma: no cover
+
+
+def _setop_impl(a, b, setop: int, pallas: bool, interpret: bool):
     if pallas:
         from ..kernels.bitmap_ops import bitmap_setop
         out, pops = bitmap_setop(a, b, setop, interpret=interpret)
         return out, pops[:, 0]
     from ..kernels import ref
-    if setop == OP_AND:
-        out = a & b
-    elif setop == OP_OR:
-        out = a | b
-    elif setop == OP_ANDNOT:
-        out = a & jnp.bitwise_not(b)
-    else:  # pragma: no cover
-        raise ValueError(f"bad setop {setop}")
+    out = _setop_bits(a, b, setop)
     return out, ref.popcount_ref(out)
 
 
@@ -377,6 +394,118 @@ _TAPE_PROGRAMS: "OrderedDict[tuple, object]" = OrderedDict()
 _TAPE_PROGRAM_CAP = 256
 
 
+class _BackgroundCompiler:
+    """Compiles whole-tape programs on one daemon thread.
+
+    Tiered execution of a session's batches
+    (:meth:`DeviceTapeBackend.run_tapes`): a tape whose program is not
+    compiled yet for its argument shapes runs op by op over the shared
+    per-op prims, and its program is queued here (once per program and
+    shapes), so no batch waits seconds on the compiler for a plan it has
+    not met.  A job holds the arguments' abstract values, never the
+    arrays, and ``prog.lower(*avals).compile()`` fills the executable
+    cache a call of ``prog`` reads, so the tape's next run is one dispatch
+    with no trace and no compile.  Each compile runs in a ``tape.compile``
+    span of the tracer the batch passed, so the compile listener of
+    :mod:`~repro.columnar.trace` records its ``jax.compile`` there.  A
+    compile that fails leaves the tape on the op-by-op path for good.
+    """
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        # program -> {argument signature: compiled}; weak, so an evicted
+        # program takes its entries with it
+        self._done: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
+        self._queued: set = set()
+        self._jobs: "queue.Queue" = queue.Queue()
+        self._thread: Optional[threading.Thread] = None
+        #: tracebacks of the compiles that failed
+        self.failures: List[str] = []
+
+    @staticmethod
+    def _avals(args):
+        """What a call's executable is keyed by: each array's shape, dtype,
+        weak type and, when it is committed to devices, its sharding."""
+        import jax
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, weak_type=x.weak_type,
+                sharding=x.sharding if x.committed else None), args)
+
+    @staticmethod
+    def _signature(avals) -> tuple:
+        import jax
+        leaves, tree = jax.tree_util.tree_flatten(avals)
+        return tree, tuple((a.shape, a.dtype, a.weak_type, a.sharding)
+                           for a in leaves)
+
+    def ready(self, prog, args, tracer=None) -> bool:
+        """True when a call ``prog(*args)`` runs compiled code; otherwise
+        queues the compile (if it is not queued already, in a span of
+        ``tracer``) and returns False."""
+        avals = self._avals(args)
+        sig = self._signature(avals)
+        with self._lock:
+            done = self._done.get(prog, {})
+            if sig in done:
+                return done[sig]
+            if (id(prog), sig) not in self._queued:
+                self._queued.add((id(prog), sig))
+                self._jobs.put((prog, avals, sig, tracer))
+                if self._thread is None:
+                    self._thread = threading.Thread(
+                        target=self._work, name="tape-compile", daemon=True)
+                    self._thread.start()
+                    atexit.register(self._stop)
+        return False
+
+    def wait(self, timeout: float) -> bool:
+        """Block until no compile is queued or running; False if
+        ``timeout`` seconds pass first."""
+        deadline = time.monotonic() + timeout
+        while time.monotonic() < deadline:
+            with self._lock:
+                if not self._queued:
+                    return True
+            time.sleep(0.01)
+        return False
+
+    def _work(self) -> None:
+        while True:
+            job = self._jobs.get()
+            if job is None:
+                return
+            prog, avals, sig, tracer = job
+            span = (tracer.span("tape.compile") if tracer is not None
+                    else contextlib.nullcontext())
+            try:
+                with span:
+                    prog.lower(*avals).compile()
+                ok = True
+            except Exception:       # the op-by-op path keeps serving
+                ok = False
+                self.failures.append(traceback.format_exc())
+                warnings.warn("whole-tape program failed to compile; the "
+                              "tape keeps running op by op:\n"
+                              + self.failures[-1], RuntimeWarning)
+            with self._lock:
+                self._queued.discard((id(prog), sig))
+                self._done.setdefault(prog, {})[sig] = ok
+            del job, prog, avals, tracer
+
+    def _stop(self) -> None:
+        """At exit: drop queued compiles and let the one in flight end
+        before the runtime tears down."""
+        with self._lock:
+            while not self._jobs.empty():
+                self._jobs.get_nowait()
+            self._jobs.put(None)
+        self._thread.join(timeout=300)
+
+
+_COMPILER = _BackgroundCompiler()
+
+
 def _tape_forward(ops, meta, result, n_slots, prune, skip, pallas, interpret,
                   cols, values, lmasks, zmasks, full_bits, full_pops):
     """The whole-tape op loop, as a pure function of device arrays.
@@ -395,6 +524,7 @@ def _tape_forward(ops, meta, result, n_slots, prune, skip, pallas, interpret,
     counter vectors that ride the one bundled transfer.
     """
     import jax.numpy as jnp
+    from ..kernels import ref
     bits: List[object] = [None] * n_slots
     pops: List[object] = [None] * n_slots
     recs, blks, prns, outs = [], [], [], []
@@ -405,12 +535,18 @@ def _tape_forward(ops, meta, result, n_slots, prune, skip, pallas, interpret,
         elif op.kind == EMPTY:
             b = jnp.zeros_like(full_bits)
             p = jnp.zeros_like(full_pops)
+        elif op.kind == SETOP and not pallas:
+            # the popcounts of a set op's result are only read if an atom
+            # op takes it as its source: count them there, on demand
+            b, p = _setop_bits(bits[op.a], bits[op.b], op.setop), None
         elif op.kind == SETOP:
             b, p = _setop_impl(bits[op.a], bits[op.b], op.setop,
                                pallas, interpret)
         else:
             cixs, vixs, opcodes = meta[oi]
             sb, sp = bits[op.a], pops[op.a]
+            if sp is None:
+                sp = ref.popcount_ref(sb)
             # records_evaluated stays the PRE-prune popcount (the
             # paper metric describes the plan, not the pruning);
             # blocks split into touched (live MAYBE) and pruned
@@ -499,14 +635,14 @@ class DeviceTapeBackend(SetBackend):
         :meth:`_account`, the feedback popcounts of :meth:`_fb_queue`, the
         stack and unstack of the multi paths, zone-verdict and lookup-mask
         uploads, ``full``/``empty`` set uploads, and :meth:`materialize`'s
-        counter stacks and result flattening.  A zone-pruned compare atom
+        counter stacks.  A zone-pruned compare atom
         through :meth:`apply_atom` issues :data:`ZONED_ATOM_BOOKKEEPING`.
     ``zone_host_s``
         host seconds in :meth:`_zone_mask`
 
     Host seconds accrue only in the calls the executors make while
     dispatching (``apply_atom*``, the set ops, ``inter_multi``,
-    ``run_tape``), at one ``perf_counter`` pair per timed stretch, and the
+    ``run_tapes``), at one ``perf_counter`` pair per timed stretch, and the
     four never overlap; launches count wherever they happen (the OR of
     :meth:`extend_set` during upload, :meth:`materialize` during sync).
     No counter adds a sync.
@@ -535,6 +671,8 @@ class DeviceTapeBackend(SetBackend):
         self.host_syncs = 0
         self.host_fallbacks = 0
         self.device_dispatches = 0
+        # tapes run_tapes ran op by op while their program compiled
+        self.cold_tapes = 0
         self.uploaded_bytes = 0       # host->device column traffic
         # the dispatch split (see the class docstring)
         self.kernel_launches = 0
@@ -761,15 +899,18 @@ class DeviceTapeBackend(SetBackend):
         self._lap("bookkeeping_host_s", t0)
         return out
 
-    def _flat_device(self, d: _DevSet):
-        """Blocked device bitmap -> flat device words (real length)."""
-        return d.bits.reshape(-1)[: n_words(self.n)]
+    def _cut(self, bits) -> np.ndarray:
+        """Fetched bucket-padded ``u32[N, W]`` bits -> host flat packed
+        words of the real length.  The cut is made on the host, so no
+        device op takes its shape from the row count (an append inside the
+        block bucket compiles nothing)."""
+        return np.asarray(bits).reshape(-1)[: n_words(self.n)]
 
     def _pull_flat(self, d: _DevSet) -> np.ndarray:
         """One host sync: fetch a device set as host flat packed words."""
         import jax
         self.host_syncs += 1
-        return np.asarray(jax.device_get(self._flat_device(d)))
+        return self._cut(jax.device_get(d.bits))
 
     # -- SetBackend ------------------------------------------------------------
     def full(self) -> _DevSet:
@@ -1056,10 +1197,15 @@ class DeviceTapeBackend(SetBackend):
     def materialize(self, sets: Sequence[_DevSet]) -> List[np.ndarray]:
         """Fetch result bitmaps AND flush pending cost counters in one
         bundled transfer — the query/batch's single host sync."""
+        return self._materialize(sets)[0]
+
+    def _materialize(self, sets: Sequence[_DevSet], extra=None):
+        """:meth:`materialize`, with ``extra`` (any pytree of device
+        arrays) riding the same transfer; returns ``(bitmaps, fetched
+        extra)``."""
         import jax
         import jax.numpy as jnp
         _faults.trip("device.dispatch", backend=self, where="materialize")
-        flats = [self._flat_device(d) for d in sets]
         if self._pend_records:
             rec = jnp.stack(self._pend_records)
             blk = jnp.stack(self._pend_blocks)
@@ -1068,11 +1214,12 @@ class DeviceTapeBackend(SetBackend):
             rec = jnp.zeros((0,), dtype=jnp.int32)
             blk = jnp.zeros((0,), dtype=jnp.int32)
             prn = jnp.zeros((0,), dtype=jnp.int32)
-        # a reshape and a slice per result, the three counter vectors
-        self.bookkeeping_launches += 2 * len(flats) + 3
+        # the three counter vectors
+        self.bookkeeping_launches += 3
         self.host_syncs += 1
-        flats, rec, blk, prn, fsrc, fout = jax.device_get(
-            (flats, rec, blk, prn, self._fb_src, self._fb_out))
+        bits, rec, blk, prn, fsrc, fout, extra = jax.device_get(
+            ([d.bits for d in sets], rec, blk, prn, self._fb_src,
+             self._fb_out, extra))
         rec = np.asarray(rec, dtype=np.float64)
         blk = np.asarray(blk, dtype=np.float64)
         ks = np.asarray(self._pend_k, dtype=np.float64)
@@ -1093,7 +1240,7 @@ class DeviceTapeBackend(SetBackend):
             for sj, oj in zip(s, o):
                 self._log_op(keys, est, int(sj), int(oj))
         self._fb_meta, self._fb_src, self._fb_out = [], [], []
-        return [np.asarray(f) for f in flats]
+        return [self._cut(b) for b in bits], extra
 
     def _host_atom_group(self, op, src: _DevSet) -> _DevSet:
         """Host fallback for a tape ATOM/CHAIN op: zone-pruned gather of
@@ -1252,67 +1399,126 @@ class DeviceTapeBackend(SetBackend):
         return prog
 
     def run_tape(self, tape: PlanTape) -> np.ndarray:
-        """Execute a compiled tape; returns the host packed result bitmap.
+        """Execute one compiled tape; returns the host packed result
+        bitmap.  Its program compiles on the call if it must, so an
+        all-device tape is one dispatch and one host sync
+        (``run_query``)."""
+        return self._run_tapes([tape], tiered=False, tracer=None)[0]
 
-        All-device tapes — including dictionary-rewritten string atoms —
-        run as ONE jitted dispatch and ONE host sync.  Tapes with host-
-        fallback ops (opaque UDF atoms, unrewritten non-numeric columns)
-        run op-by-op with device slots, syncing only at each fallback and
-        at the end.  The whole-tape path's own sync (``device_get``) is
-        in no dispatch counter's host seconds.
+    def run_tapes(self, tapes: Sequence[PlanTape], tracer=None
+                  ) -> List[np.ndarray]:
+        """Execute a session's batch of compiled tapes; returns their host
+        packed result bitmaps, in order.
+
+        Every all-device tape — dictionary-rewritten string atoms included
+        — whose program is compiled is launched as ONE jitted program
+        first; then ONE host sync (``device_get``) brings back every
+        result with its counter vectors, so a batch of tapes pays one
+        sync, not one per tape.  Results come back bucket-padded and are
+        cut to the row count on the host, so no device op takes its shape
+        from the row count.
+
+        Tiered: a tape whose program is not compiled yet for its shapes
+        runs op by op over device slots while :data:`_COMPILER` compiles
+        the program on its own thread (in a ``tape.compile`` span of
+        ``tracer``), so no batch waits on the compiler and the tape's next
+        run is one dispatch; :attr:`cold_tapes` counts these runs.  Tapes
+        with host-fallback ops (opaque UDF atoms, unrewritten non-numeric
+        columns) run op by op too, syncing at each fallback.  An op-by-op
+        run's result and queued counters ride the same bundled transfer.
+        The bundled sync is in no dispatch counter's host seconds.
         """
+        return self._run_tapes(tapes, tiered=True, tracer=tracer)
+
+    def _run_tapes(self, tapes: Sequence[PlanTape], tiered: bool, tracer
+                   ) -> List[np.ndarray]:
+        import jax
+        out: List[Optional[np.ndarray]] = [None] * len(tapes)
+        progs = []       # (index, tape, program outputs)
+        sets = []        # (index, device set of an op-by-op run)
+        for i, tape in enumerate(tapes):
+            _faults.trip("device.dispatch", backend=self, where="run_tape")
+            dev = self._launch_tape(tape, tiered, tracer)
+            if isinstance(dev, _DevSet):
+                sets.append((i, dev))
+            else:
+                progs.append((i, tape, dev))
+        fetched = []
+        if sets:
+            bitmaps, fetched = self._materialize(
+                [d for _, d in sets], [dev for _, _, dev in progs])
+            for (i, _), b in zip(sets, bitmaps):
+                out[i] = b
+        elif progs:
+            self.host_syncs += 1
+            fetched = jax.device_get([dev for _, _, dev in progs])
+        for (i, tape, _), (res, rec, blk, prn, outs) in zip(progs, fetched):
+            self._account_tape(tape, rec, blk, prn, outs)
+            out[i] = self._cut(res)
+        return out
+
+    def _launch_tape(self, tape: PlanTape, tiered: bool, tracer):
+        """Bind and launch a tape: an all-device tape's compiled program
+        returns its device outputs ``(result bits, rec, blk, prn, outs)``;
+        an op-by-op run (host-fallback ops, or a ``tiered`` tape whose
+        program is still compiling) returns its result set.  Nothing
+        syncs here but a host fallback."""
         import jax.numpy as jnp
-        _faults.trip("device.dispatch", backend=self, where="run_tape")
         self.last_tape = tape
         t0 = time.perf_counter()
         cols, values, lmasks, meta, device_ok = self._tape_bindings(tape)
         self._lap("kernel_host_s", t0)
-        atoms = tape.tree.atoms
+        if not all(device_ok):
+            return self._run_tape_ops(tape, lmasks, meta, device_ok)
         full = self.full()
-        if all(device_ok):
-            costed = tape.costed_ops()
-            # a K-atom CHAIN evaluates K atoms on all of src's live blocks:
-            # counts scale by K, matching the fused +evaluations trade
-            ks = np.asarray([len(op.aids) for op in costed],
-                            dtype=np.float64)
-            self.stats.atom_applications += int(ks.sum())
-            self.stats.setops += sum(1 for op in tape.ops
-                                     if op.kind == SETOP)
-            zmasks, any_decided = self._tape_zone_masks(tape)
-            vj = self._eager(jnp.asarray, values, dtype=jnp.float32)
-            lj = self._eager(jnp.asarray, lmasks)
-            t0 = time.perf_counter()
-            prog = self._tape_program(tape, tuple(meta), skip=any_decided)
-            self.device_dispatches += 1
-            self.kernel_launches += 1
-            res, rec, blk, prn, outs = prog(tuple(cols), vj, lj, zmasks,
-                                            full.bits, full.pops)
-            self._lap("kernel_host_s", t0)
-            import jax
-            self.host_syncs += 1
-            res, rec, blk, prn, outs = jax.device_get(
-                (res.reshape(-1)[: n_words(self.n)], rec, blk, prn, outs))
-            rec = np.asarray(rec, dtype=np.float64)
-            weights = np.asarray([sum(atoms[a].cost_factor
-                                      for a in op.aids) for op in costed])
-            self.stats.records_evaluated += float((rec * ks).sum())
-            self.stats.weighted_cost += float((rec * weights).sum())
-            blk_total = float((np.asarray(blk, dtype=np.float64) * ks).sum())
-            self.blocks_touched += blk_total
-            self.records_touched += blk_total * self.block
-            self.blocks_pruned += float(
-                (np.asarray(prn, dtype=np.float64) * ks).sum())
-            # per-op realized selectivities rode the same device_get:
-            # (keys, est) metadata is tape-order aligned with rec/outs
-            for (opm, keys, est), s, o in zip(op_observation_meta(tape),
-                                              rec, np.asarray(outs)):
-                self._log_op(keys, est, int(s), int(o))
-            return np.asarray(res)
-        return self._run_tape_mixed(tape, lmasks, meta, device_ok)
+        zmasks, any_decided = self._tape_zone_masks(tape)
+        vj = self._eager(jnp.asarray, values, dtype=jnp.float32)
+        lj = self._eager(jnp.asarray, lmasks)
+        prog = self._tape_program(tape, tuple(meta), skip=any_decided)
+        args = (tuple(cols), vj, lj, zmasks, full.bits, full.pops)
+        if tiered and not _COMPILER.ready(prog, args, tracer):
+            self.cold_tapes += 1
+            return self._run_tape_ops(tape, lmasks, meta, device_ok)
+        self.stats.atom_applications += sum(len(op.aids)
+                                            for op in tape.costed_ops())
+        self.stats.setops += sum(1 for op in tape.ops if op.kind == SETOP)
+        t0 = time.perf_counter()
+        self.device_dispatches += 1
+        self.kernel_launches += 1
+        dev = prog(*args)
+        self._lap("kernel_host_s", t0)
+        return dev
 
-    def _run_tape_mixed(self, tape: PlanTape, lmasks, meta, device_ok
-                        ) -> np.ndarray:
-        """Op-by-op tape execution with host fallbacks interleaved."""
+    def _account_tape(self, tape: PlanTape, rec, blk, prn, outs) -> None:
+        """Fold one tape's fetched per-costed-op counters into the cost
+        stats and the feedback log.  A K-atom CHAIN evaluates K atoms on
+        all of src's live blocks: counts scale by K, matching the fused
+        +evaluations trade."""
+        atoms = tape.tree.atoms
+        costed = tape.costed_ops()
+        ks = np.asarray([len(op.aids) for op in costed], dtype=np.float64)
+        rec = np.asarray(rec, dtype=np.float64)
+        weights = np.asarray([sum(atoms[a].cost_factor for a in op.aids)
+                              for op in costed])
+        self.stats.records_evaluated += float((rec * ks).sum())
+        self.stats.weighted_cost += float((rec * weights).sum())
+        blk_total = float((np.asarray(blk, dtype=np.float64) * ks).sum())
+        self.blocks_touched += blk_total
+        self.records_touched += blk_total * self.block
+        self.blocks_pruned += float(
+            (np.asarray(prn, dtype=np.float64) * ks).sum())
+        # per-op realized selectivities rode the same device_get:
+        # (keys, est) metadata is tape-order aligned with rec/outs
+        for (opm, keys, est), s, o in zip(op_observation_meta(tape),
+                                          rec, np.asarray(outs)):
+            self._log_op(keys, est, int(s), int(o))
+
+    def _run_tape_ops(self, tape: PlanTape, lmasks, meta, device_ok
+                      ) -> _DevSet:
+        """Op-by-op tape execution over device slots, host fallbacks
+        interleaved: the jitted per-op prims the lockstep executor uses,
+        with their cost counters queued for the next bundled transfer.
+        Returns the result set, unsynced."""
         import jax.numpy as jnp
         prims = _jitted_prims()
         slots: List[Optional[_DevSet]] = [None] * tape.n_slots
@@ -1374,4 +1580,4 @@ class DeviceTapeBackend(SetBackend):
                     self._fb_queue(grp, op.conj, src.pops, pops)
                     s = _DevSet(out, pops)
             slots[op.dst] = s
-        return self.materialize([slots[tape.result]])[0]
+        return slots[tape.result]

@@ -20,7 +20,9 @@ atom dedupe      atoms whose :func:`~repro.core.predicate.atom_key` appears
                  touches its column once per batch.
 
 lockstep batching
-                 with ``batched=True`` (default for the block engines) the
+                 with ``batched=True`` (the ``"auto"`` choice on the block
+                 engines; the tape engines run compiled whole-plan tapes
+                 unless asked) the
                  ordering-based plans are driven round-by-round through
                  :class:`~repro.core.bestd.BestDMachine` (correct for any
                  ordering, Thm 4); requests for the same atom arriving in
@@ -506,10 +508,13 @@ class QuerySession:
                       (:class:`~repro.columnar.device.DeviceTapeBackend`):
                       by default each plan compiles to a
                       :class:`~repro.core.tape.PlanTape` executed as ONE
-                      device program with one host sync per query, while
-                      ``batched=True`` instead drives the lockstep executor
-                      over device sets (fused multi-query atom kernels, one
-                      bundled host sync per batch).
+                      device program (a plan whose program is not compiled
+                      yet runs op by op while it compiles off the batch),
+                      and one bundled host sync brings back the whole
+                      batch, while ``batched=True`` instead
+                      drives the lockstep executor over device sets (fused
+                      multi-query atom kernels, one jitted call per atom
+                      round and set op, one bundled host sync per batch).
     plan_cache:       an :class:`LRUPlanCache`; persists across ``execute``
                       calls (and may be shared between sessions)
     share_threshold:  min queries an atom key must appear in to become a
@@ -789,7 +794,8 @@ class QuerySession:
         planners = [self._resolve_planner(t) for t in trees]
         # "auto": lockstep for the per-step block engines (their win is the
         # fused multi-query kernel); compiled whole-plan tapes for the
-        # device engines (their win is one dispatch + one sync per query).
+        # device engines (their win is one dispatch per query and one
+        # bundled sync per batch).
         # batched=True forces device-resident lockstep on any block engine.
         tape_engine = self.engine in ("tape", "tape-pallas")
         lockstep = ((self.batched is True
@@ -873,11 +879,17 @@ class QuerySession:
         base_applications = inner.stats.atom_applications
         base_records = inner.stats.records_evaluated
         base_cost = inner.stats.weighted_cost
-        with sp("batch.dispatch", lockstep=lockstep, tapes=use_tapes):
+        with sp("batch.dispatch", lockstep=lockstep,
+                tapes=use_tapes) as dsp:
             if lockstep:
                 bitmaps = self._execute_lockstep(trees, plans, sb, stats)
             elif tape_engine:
-                bitmaps = [inner.run_tape(tp) for tp in tapes]
+                # every compiled query program launches, then one bundled
+                # sync; cold = queries run op by op while their program
+                # compiles off the batch
+                cold0 = inner.cold_tapes
+                bitmaps = inner.run_tapes(tapes, tracer=self.tracer)
+                dsp.set(cold=inner.cold_tapes - cold0)
                 stats.logical_atoms += sum(len(p.tree.atoms) for p in plans)
             else:
                 bitmaps = [execute_plan(p, sb) for p in plans]

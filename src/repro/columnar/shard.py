@@ -20,10 +20,10 @@ jits (:func:`repro.columnar.device._tape_forward`), wrapped in
 (the forward has no cross-block ops, so per-shard results are exact), then
 ONE collective — ``all_gather`` for the result bitmap, ``psum`` for the
 counter vectors — produces replicated outputs.  The inherited
-:meth:`~repro.columnar.device.DeviceTapeBackend.run_tape` then makes its
+:meth:`~repro.columnar.device.DeviceTapeBackend.run_tapes` then makes its
 usual single bundled ``device_get``: the one-sync contract survives
-sharding as one *collective* sync per query (``host_syncs == 1``), and a
-lockstep batch keeps one bundled collective sync via the inherited
+sharding as one *collective* sync per batch of tapes (``host_syncs == 1``),
+and a lockstep batch keeps one bundled collective sync via the inherited
 :meth:`materialize`.
 
 Appends stay shard-local: :meth:`refresh` re-uploads only the dirty tail
@@ -57,7 +57,7 @@ from .table import Table
 
 class ShardedTapeBackend(DeviceTapeBackend):
     """Multi-device tape executor: block-sharded columns, one collective
-    sync per query.
+    sync per batch of tapes.
 
     Parameters mirror :class:`DeviceTapeBackend` plus:
 
@@ -164,7 +164,7 @@ class ShardedTapeBackend(DeviceTapeBackend):
         """Same cache, same forward, one wrapper: the single-device op
         loop runs per shard over its block slice inside ``shard_map``, and
         the outputs reduce with one ``all_gather``/``psum`` collective to
-        replicated arrays — so the inherited ``run_tape`` / ``materialize``
+        replicated arrays — so the inherited ``run_tapes`` / ``materialize``
         bundling (and their ``host_syncs == 1`` accounting) apply verbatim.
         Appends never retrace here either: the zone masks stay runtime
         inputs, and the cache key only adds the mesh identity."""

@@ -5,9 +5,10 @@ subsystem: queries are *admitted* into an in-flight batch
 (:meth:`submit` returns a :class:`StreamFuture` immediately) while rows
 keep appending (:meth:`append`) and dying (:meth:`delete`), and batches
 *drain* through a :class:`~repro.columnar.multiquery.QuerySession` — by
-default the device-resident lockstep tape executor, whose
-one-bundled-host-sync-per-batch contract is untouched because a drain is
-just one ``QuerySession.execute`` call.
+default on the device tape engine, where each query of a drain runs as its
+own compiled whole-plan tape (one device program) and one bundled host
+sync brings back the whole drain, because a drain is just one
+``QuerySession.execute`` call.
 
 Consistency contract — **snapshot-at-drain**: every query in a drained
 batch evaluates against the table state at drain time (the paper's
@@ -265,10 +266,14 @@ class StreamSession:
     and deletes.
 
     Execution is configured with ``config=ExecConfig(...)`` exactly like
-    :class:`QuerySession`; the stream defaults differ (``engine="tape"`` +
-    ``batched=True``: drains run the device-resident lockstep executor,
-    one bundled host sync per batch — one bundled *collective* sync under
-    ``shards > 1``).  Every legacy execution kwarg is an explicit
+    :class:`QuerySession`; the stream defaults differ (``engine="tape"``,
+    ``planner="deepfish"``: each query of a drain runs as one compiled
+    whole-plan tape program, and one bundled host sync per drain brings
+    back every result — one bundled *collective* sync under
+    ``shards > 1``; a plan whose program is not compiled yet runs op by
+    op while it compiles off the drain; ``batched=True`` selects the
+    lockstep executor).
+    Every legacy execution kwarg is an explicit
     parameter routed through the deprecation shim — the old blind
     ``**session_kwargs`` forwarding is gone, so a typo'd kwarg is a
     ``TypeError`` instead of silently reaching :class:`QuerySession`.
@@ -307,9 +312,8 @@ class StreamSession:
     """
 
     #: stream-flavored execution defaults (vs ExecConfig's conservative
-    #: numpy/auto): drains lockstep the device tape engine
-    DEFAULT_CONFIG = ExecConfig(planner="deepfish", engine="tape",
-                                batched=True)
+    #: numpy/auto): drains run compiled whole-plan tapes on the device
+    DEFAULT_CONFIG = ExecConfig(planner="deepfish", engine="tape")
 
     def __init__(self, table: Optional[Table], planner=UNSET,
                  engine=UNSET, max_pending: int = 64,

@@ -28,12 +28,17 @@ Compilation pipeline:
    children of one inner node, (b) are all device-evaluable comparisons, and
    (c) appear consecutively in the order, are emitted as a single CHAIN op
    and absorbed into the machine via
-   :meth:`~repro.core.bestd.BestDMachine.absorb_chain`.  Fusing only whole
+   :meth:`~repro.core.bestd.BestDMachine.absorb_chain`.  A CHAIN lists its
+   atoms in (column, op) order, not the plan's, so chains that differ only
+   in order share one key.  Fusing only whole
    leaf groups is what makes this safe: no lineage outside the group ever
    references an individual fused atom, only the (now complete) parent node.
-3. **Dead-code elimination** — BestD's Delta bookkeeping emits ops whose
+3. **Set-op CSE** — BestD's bookkeeping recomputes the same unions and
+   complements at every step; a set op that repeats an earlier one on
+   the same operands is merged into it (about half of a TPC-H Q19 tape).
+4. **Dead-code elimination** — BestD's Delta bookkeeping emits ops whose
    results never reach the root Xi; a backward liveness pass drops them.
-4. **Slot allocation** — virtual SSA slots are remapped onto a minimal set
+5. **Slot allocation** — virtual SSA slots are remapped onto a minimal set
    of physical slots by linear scan (a slot is recycled after its last
    read), bounding the device slot buffer ``u32[S, N, W]``.
 
@@ -214,6 +219,15 @@ def op_observation_meta(tape: PlanTape
     return out
 
 
+def _chain_aids(atoms: Sequence[Atom]) -> Tuple[int, ...]:
+    """A CHAIN's atom ids in (column, op) order.  A chain evaluates every
+    atom on the same source blocks and combines them with one connective,
+    so its order is free: chains that differ only in the plan's order get
+    one structural key, and so one compiled program."""
+    return tuple(a.aid for a in sorted(atoms, key=lambda a: (a.column,
+                                                             a.op)))
+
+
 class _TapeEmitter(SetBackend):
     """Op-recording backend: every call returns a fresh virtual slot id."""
 
@@ -261,8 +275,8 @@ class _TapeEmitter(SetBackend):
 
     def apply_chain(self, atoms: Sequence[Atom], conj: bool, d):
         s = self._slot()
-        self.ops.append(TapeOp(CHAIN, s, a=d,
-                               aids=tuple(a.aid for a in atoms), conj=conj))
+        self.ops.append(TapeOp(CHAIN, s, a=d, aids=_chain_aids(atoms),
+                               conj=conj))
         return s
 
     def count(self, d) -> float:  # pragma: no cover - trace-time guard
@@ -294,6 +308,49 @@ def _chain_group(tree: PredicateTree, order: Sequence[int], i: int,
     if set(run) != kid_aids:
         return None
     return run
+
+
+def _cse(ops: List[TapeOp], result: int) -> Tuple[List[TapeOp], int]:
+    """Common-subexpression elimination of SSA set ops.
+
+    BestD's bookkeeping recomputes the same unions and complements at
+    every step (``full \\ (a ∪ b)`` once per atom of a deep tree); a set op
+    whose operands resolve to the same values as an earlier one's is
+    dropped and its readers read the earlier result.  ``x ∧ x``,
+    ``x ∨ x``, ``x ∧ full``, ``x ∨ empty`` and ``x \\ empty`` resolve to
+    ``x``.  Only SETOPs merge, so every costed ATOM/CHAIN op — and the
+    paper's cost — stays as traced.
+    """
+    alias = {}
+    seen = {}
+    full = next((op.dst for op in ops if op.kind == FULL), None)
+    empty = next((op.dst for op in ops if op.kind == EMPTY), None)
+    kept: List[TapeOp] = []
+    for op in ops:
+        a, b = alias.get(op.a, op.a), alias.get(op.b, op.b)
+        if op.kind != SETOP:
+            kept.append(op if a == op.a else TapeOp(
+                op.kind, op.dst, a=a, aids=op.aids, conj=op.conj))
+            continue
+        if op.setop in (OP_AND, OP_OR):
+            unit = full if op.setop == OP_AND else empty
+            if a == b or b == unit:
+                alias[op.dst] = a
+                continue
+            if a == unit:
+                alias[op.dst] = b
+                continue
+            a, b = min(a, b), max(a, b)
+        elif b == empty:
+            alias[op.dst] = a
+            continue
+        key = (op.setop, a, b)
+        if key in seen:
+            alias[op.dst] = seen[key]
+            continue
+        seen[key] = op.dst
+        kept.append(TapeOp(SETOP, op.dst, a=a, b=b, setop=op.setop))
+    return kept, alias.get(result, result)
 
 
 def _dce(ops: List[TapeOp], result: int) -> List[TapeOp]:
@@ -355,11 +412,14 @@ def rebind_tape(tape: PlanTape, tree: PredicateTree,
     time, and shares the jitted device program whenever its structural
     ``key`` matches (same columns and ops, drifted constants).
     """
-    ops = tuple(
-        op if not op.aids else TapeOp(
-            op.kind, op.dst, a=op.a, b=op.b, setop=op.setop,
-            aids=tuple(aid_map[a] for a in op.aids), conj=op.conj)
-        for op in tape.ops)
+    def remap(op):
+        aids = [aid_map[a] for a in op.aids]
+        if op.kind == CHAIN:
+            aids = _chain_aids([tree.atoms[a] for a in aids])
+        return TapeOp(op.kind, op.dst, a=op.a, b=op.b, setop=op.setop,
+                      aids=tuple(aids), conj=op.conj)
+
+    ops = tuple(remap(op) if op.aids else op for op in tape.ops)
     return PlanTape(tree=tree, ops=ops, result=tape.result,
                     n_slots=tape.n_slots, planner=tape.planner)
 
@@ -393,7 +453,8 @@ def compile_tape(plan: Plan, chain: bool = True) -> PlanTape:
                 machine.apply_step(order[i])
                 i += 1
         result = machine.result()
-    ops = _dce(em.ops, result)
+    ops, result = _cse(em.ops, result)
+    ops = _dce(ops, result)
     ops, result, n_slots = _alloc_slots(ops, result)
     return PlanTape(tree=tree, ops=tuple(ops), result=result,
                     n_slots=n_slots, planner=plan.planner)

@@ -11,7 +11,7 @@ of monkeypatched stand-ins.
 
 Sites wired in this repo:
 
-``device.dispatch``  raised from ``DeviceTapeBackend.run_tape`` /
+``device.dispatch``  raised from ``DeviceTapeBackend.run_tapes`` /
                      ``materialize`` (the bundled sync) — models a device
                      OOM / ``XlaRuntimeError`` mid-drain.
 ``device.upload``    raised from ``DeviceTapeBackend.refresh()`` — a

@@ -175,13 +175,12 @@ def _smoke() -> int:                            # pragma: no cover - CLI
     """Self-check used by CI: real session, real socket, all routes."""
     from urllib.request import urlopen
 
-    from ..columnar import ExecConfig, StreamSession, make_forest_table
+    from ..columnar import StreamSession, make_forest_table
     from ..columnar.queries import random_query_suite
     from ..runtime.telemetry import parse_prometheus
 
     table = make_forest_table(4_000, n_dup=2, seed=7)
-    cfg = ExecConfig(planner="deepfish", engine="tape", batched=True)
-    with StreamSession(table, config=cfg) as session:
+    with StreamSession(table) as session:
         queries = random_query_suite(table, 3, 4, 2, seed=1)
         futs = [session.submit(q) for q in queries]
         for f in futs:

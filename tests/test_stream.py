@@ -1,10 +1,19 @@
 """Streaming admission layer + delta-reuse accounting + tape-cache reuse."""
+import sys
+import threading
+import time
+
+import jax
 import numpy as np
 import pytest
 
 from repro.columnar import (QuerySession, StreamQueryError, StreamSession,
-                            make_forest_table, random_tree, run_query)
-from repro.core import Atom
+                            Table, Tracer, make_forest_table, random_tree,
+                            run_query)
+from repro.columnar.device import (_COMPILER, _TAPE_PROGRAMS,
+                                   _BackgroundCompiler)
+from repro.columnar.trace import COMPILE_EVENT
+from repro.core import And, Atom, Or, normalize
 from repro.serve import RequestRouter
 
 
@@ -72,26 +81,33 @@ def test_stream_snapshot_at_drain_sees_interleaved_appends():
 
 
 def test_stream_tape_one_bundled_sync_per_drain():
-    t = make_forest_table(8000, n_dup=1, seed=7)
-    stream = StreamSession(t, engine="tape", block=4096, max_pending=64)
-    rng = np.random.default_rng(5)
-    queries = [random_tree(t, 4, 2, rng) for _ in range(4)]
-    for q in queries:
-        stream.submit(q)
-    stream.drain()
-    be = stream.session._backend
-    assert be.host_syncs == 1                      # one bundled sync
-    stream.append(_rows_like(t, 1000, seed=12))
-    futs = [stream.submit(q) for q in queries]
-    res = stream.drain()
-    assert be.host_syncs == 2                      # still one per drain
-    for f, q in zip(futs, queries):
-        np.testing.assert_array_equal(f.result(), _oracle(t, q))
-    # the drain after the append reused the device columns: only the dirty
-    # tail re-uploaded, and cached atom results were spliced, not redone
-    assert 0 < res.stats.upload_bytes < be.uploaded_bytes
-    assert res.stats.atoms_delta_extended > 0
-    assert res.stats.delta_reuse_ratio > 0.5
+    # the default (per-query compiled tapes) and the lockstep executor
+    # alike: one bundled host sync per drain, before and after an append
+    for batched in ("auto", True):
+        t = make_forest_table(8000, n_dup=1, seed=7)
+        stream = StreamSession(t, config=StreamSession.DEFAULT_CONFIG.replace(
+            block=4096, batched=batched), max_pending=64)
+        rng = np.random.default_rng(5)
+        queries = [random_tree(t, 4, 2, rng) for _ in range(4)]
+        for q in queries:
+            stream.submit(q)
+        stream.drain()
+        be = stream.session._backend
+        assert be.host_syncs == 1                  # one bundled sync
+        stream.append(_rows_like(t, 1000, seed=12))
+        futs = [stream.submit(q) for q in queries]
+        res = stream.drain()
+        assert be.host_syncs == 2                  # still one per drain
+        for f, q in zip(futs, queries):
+            np.testing.assert_array_equal(f.result(), _oracle(t, q))
+        # the drain after the append reused the device columns: only the
+        # dirty tail re-uploaded
+        assert 0 < res.stats.upload_bytes < be.uploaded_bytes
+        if batched is True:
+            # lockstep keeps atom results across drains: they were
+            # spliced, not redone
+            assert res.stats.atoms_delta_extended > 0
+            assert res.stats.delta_reuse_ratio > 0.5
 
 
 def test_stream_delta_reuse_on_host_engine():
@@ -196,3 +212,279 @@ def test_persistent_router_routes_per_call_batches():
     assert router.table.n_records == 112           # history accumulated
     # per-call cost is delta-shaped: cached atoms spliced, not re-evaluated
     assert router.last_result.stats.atoms_delta_extended > 0
+
+
+# -- the served path: per-query compiled tapes, one bundled sync per drain ---
+
+BRANDS = [f"Brand#{m}{n}" for m in range(1, 6) for n in range(1, 6)]
+CONTAINERS = [f"{a} {b}" for a in ("SM", "MED", "LG", "JUMBO", "WRAP")
+              for b in ("CASE", "BOX", "BAG", "JAR", "PKG", "PACK", "CAN",
+                        "DRUM")]
+MODES = ["REG AIR", "AIR", "RAIL", "SHIP", "TRUCK", "MAIL", "FOB"]
+INSTRUCTIONS = ["DELIVER IN PERSON", "COLLECT COD", "NONE",
+                "TAKE BACK RETURN"]
+
+
+def _lineitem(n, seed):
+    """Columns of TPC-H lineitem joined to part that Q6 and Q19 read."""
+    rng = np.random.default_rng(seed)
+    return {"l_quantity": rng.integers(1, 51, n).astype(np.int32),
+            "l_discount": rng.integers(0, 11, n).astype(np.int32),
+            "l_shipdate": rng.integers(0, 2500, n).astype(np.int32),
+            "p_size": rng.integers(1, 51, n).astype(np.int32),
+            "p_brand": rng.choice(BRANDS, n),
+            "p_container": rng.choice(CONTAINERS, n),
+            "l_shipmode": rng.choice(MODES, n),
+            "l_shipinstruct": rng.choice(INSTRUCTIONS, n)}
+
+
+def _q19(rng):
+    """Three arms of brand eq + container IN + quantity and size ranges +
+    shipmode IN + shipinstruct eq."""
+    arms = []
+    for kind, size, lo in (("SM", 5, 1), ("MED", 10, 10), ("LG", 15, 20)):
+        qty = int(rng.integers(lo, lo + 11))
+        arms.append(And([
+            Atom("p_brand", "eq", str(rng.choice(BRANDS))),
+            Atom("p_container", "in", tuple(f"{kind} {b}" for b in
+                                            ("CASE", "BOX", "PACK", "PKG"))),
+            Atom("l_quantity", "ge", qty), Atom("l_quantity", "le", qty + 10),
+            Atom("p_size", "ge", 1), Atom("p_size", "le", size),
+            Atom("l_shipmode", "in", ("AIR", "REG AIR")),
+            Atom("l_shipinstruct", "eq", "DELIVER IN PERSON")]))
+    return normalize(Or(arms))
+
+
+def _q6(rng):
+    year = int(rng.integers(0, 5)) * 365
+    disc = int(rng.integers(2, 10))
+    return normalize(And([Atom("l_shipdate", "ge", year),
+                Atom("l_shipdate", "lt", year + 365),
+                Atom("l_discount", "ge", disc - 1),
+                Atom("l_discount", "le", disc + 1),
+                Atom("l_quantity", "lt", int(rng.integers(24, 26)))]))
+
+
+def _expected(table, q):
+    want = _oracle(table, q)
+    lw = table.live_words()
+    return want if lw is None else want & lw
+
+
+def _served(table, **kw):
+    return StreamSession(table, config=StreamSession.DEFAULT_CONFIG.replace(
+        block=1024, **kw), max_pending=64)
+
+
+def _dispatch_spans(tr):
+    return [sp for sp in tr.drain() if sp.name == "batch.dispatch"]
+
+
+def test_stream_default_drain_runs_one_program_per_query():
+    _TAPE_PROGRAMS.clear()          # every plan below meets a cold program
+    t = Table(_lineitem(6000, seed=1))
+    tr = Tracer()
+    stream = _served(t, trace=tr)
+    rng = np.random.default_rng(2)
+    queries = [_q19(rng), _q6(rng), _q19(rng), _q6(rng)]
+    drains = []
+    for _ in range(2):
+        futs = [stream.submit(q) for q in queries]
+        setops = stream.session._backend.setop_launches \
+            if stream.session._backend is not None else 0
+        res = stream.drain()
+        drains.append((res.stats, _dispatch_spans(tr),
+                       res.backend.setop_launches - setops))
+        for f, q in zip(futs, queries):
+            np.testing.assert_array_equal(f.result(), _expected(t, q))
+        assert _COMPILER.wait(300)
+    (cold, (cold_disp,), cold_setops), (warm, (disp,), setops) = drains
+    # a plan met for the first time runs op by op while its program
+    # compiles off the drain (a later query of the drain may find it
+    # compiled already); one bundled sync per drain either way
+    assert cold_disp.attrs["cold"] >= 2           # the first Q19, Q6
+    assert _COMPILER.failures == []
+    assert cold.device_dispatches > len(queries) and cold_setops > 0
+    assert disp.attrs["tapes"] is True and disp.attrs["lockstep"] is False
+    assert disp.attrs["cold"] == 0
+    assert warm.lockstep_rounds == 0
+    assert warm.device_dispatches == len(queries)  # one program per query
+    assert setops == 0
+    for st in (cold, warm):
+        assert st.host_syncs == 1                  # one sync per drain
+        assert st.host_fallbacks == 0
+
+
+def test_background_compile_is_traced_and_the_next_call_compiles_nothing():
+    _TAPE_PROGRAMS.clear()
+    t = Table(_lineitem(5000, seed=10))
+    tr = Tracer()
+    stream = _served(t, trace=tr)
+    rng = np.random.default_rng(11)
+    queries = [_q19(rng), _q6(rng)]
+    for q in queries:
+        stream.submit(q)
+    stream.drain()
+    assert _COMPILER.wait(300)
+    spans = tr.drain()
+    by_seq = {sp.seq: sp for sp in spans}
+    tape_compiles = [sp for sp in spans if sp.name == "tape.compile"]
+    assert len(tape_compiles) == len(queries)
+    assert {sp.thread for sp in tape_compiles} == {"tape-compile"}
+    jax_compiles = [sp for sp in spans if sp.name == "jax.compile"
+                    and by_seq.get(sp.parent_seq) in tape_compiles]
+    assert len(jax_compiles) >= len(queries)
+    compiles = []
+    counting = threading.Event()
+
+    def listener(event, secs, **kw):
+        if event == COMPILE_EVENT and counting.is_set():
+            compiles.append(kw.get("fun_name"))
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    futs = [stream.submit(q) for q in queries]
+    counting.set()
+    try:
+        res = stream.drain()
+    finally:
+        counting.clear()
+    # the program compiled from the arguments' abstract values is the one
+    # the call runs: no trace, no compile on the drain
+    assert compiles == []
+    assert res.stats.device_dispatches == len(queries)
+    for f, q in zip(futs, queries):
+        np.testing.assert_array_equal(f.result(), _expected(t, q))
+
+
+def test_stream_default_tapes_match_lockstep_and_oracle():
+    rng = np.random.default_rng(3)
+    queries = [_q19(rng) for _ in range(3)] + [_q6(rng) for _ in range(2)]
+    extra = _lineitem(700, seed=4)
+    dead = np.random.default_rng(5).choice(6700, 300, replace=False)
+    got = {}
+    for batched in ("auto", True):
+        t = Table(_lineitem(6000, seed=1))
+        stream = _served(t, batched=batched)
+        rounds = []
+        for step in ("initial", "append", "delete"):
+            if step == "append":
+                stream.append(extra)
+            elif step == "delete":
+                stream.delete(dead)
+            futs = [stream.submit(q) for q in queries]
+            stream.drain()
+            answers = [f.result() for f in futs]
+            for a, q in zip(answers, queries):
+                np.testing.assert_array_equal(a, _expected(t, q))
+            rounds.append(answers)
+        got[batched] = rounds
+    for tapes, lockstep in zip(got["auto"], got[True]):
+        for a, b in zip(tapes, lockstep):
+            np.testing.assert_array_equal(a, b)
+
+
+def test_stream_tape_append_in_bucket_compiles_nothing():
+    # 6000 rows in blocks of 1024: bucket 8; appends of 10 rows keep both
+    # the bucket and the append boundary's block, so refresh's tail
+    # shapes are the warm-up append's
+    t = Table(_lineitem(6000, seed=6))
+    stream = _served(t)
+    rng = np.random.default_rng(7)
+    queries = [_q19(rng), _q6(rng), _q19(rng)]
+    for step in range(2):
+        if step:
+            stream.append(_lineitem(10, seed=8))
+        for q in queries:
+            stream.submit(q)
+        stream.drain()
+        assert _COMPILER.wait(300)
+    compiles = []
+    counting = threading.Event()
+
+    def listener(event, secs, **kw):
+        if event == COMPILE_EVENT and counting.is_set():
+            compiles.append(kw.get("fun_name"))
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    stream.append(_lineitem(10, seed=9))
+    futs = [stream.submit(q) for q in queries]
+    n_programs = len(_TAPE_PROGRAMS)
+    counting.set()
+    try:
+        res = stream.drain()
+    finally:
+        counting.clear()
+    assert t.n_records == 6020 and res.stats.host_syncs == 1
+    assert res.stats.device_dispatches == len(queries)
+    assert len(_TAPE_PROGRAMS) == n_programs
+    assert compiles == []
+    for f, q in zip(futs, queries):
+        np.testing.assert_array_equal(f.result(), _expected(t, q))
+
+
+class _FakeProgram:
+    """Stands in for a jitted program: counts its lowerings and keeps what
+    they were given; ``fail`` makes its compile raise."""
+
+    def __init__(self, fail=False):
+        self.fail = fail
+        self.lowered = 0
+        self.lowered_with = []
+        self._lock = threading.Lock()
+
+    def lower(self, *args):
+        with self._lock:
+            self.lowered += 1
+            self.lowered_with.append(args)
+        time.sleep(0.005)
+        return self
+
+    def compile(self):
+        if self.fail:
+            raise RuntimeError("planted compile failure")
+        return self
+
+
+@pytest.mark.filterwarnings("ignore:whole-tape program failed")
+def test_background_compiler_compiles_each_program_once_under_contention():
+    comp = _BackgroundCompiler()
+    progs = [_FakeProgram() for _ in range(3)] + [_FakeProgram(fail=True)]
+    shapes = [(jax.numpy.zeros(4, np.uint32),),
+              (jax.numpy.zeros(8, np.uint32),)]
+    jobs = [(p, a) for p in progs for a in shapes]
+    calls = []
+
+    def hammer():
+        deadline = time.monotonic() + 30
+        n = 0
+        while time.monotonic() < deadline:
+            ready = [comp.ready(p, a) for p, a in jobs]
+            n += len(ready)
+            if all(ready[:6]) and len(comp.failures) == 2:
+                break
+        calls.append(n)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=hammer) for _ in range(16)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=60)
+        assert not any(th.is_alive() for th in threads)
+    finally:
+        sys.setswitchinterval(old)
+        comp._stop()
+    assert len(calls) == 16
+    # one compile per program and shapes, however many drains asked
+    assert [p.lowered for p in progs] == [2, 2, 2, 2]
+    assert all(comp.ready(p, a) for p, a in jobs[:6])
+    # a failed compile stays failed: its tape keeps running op by op
+    assert not any(comp.ready(p, a) for p, a in jobs[6:])
+    assert progs[3].lowered == 2 and len(comp.failures) == 2
+    assert not comp._thread.is_alive()
+    # a queued job holds abstract values, never the device arrays
+    for p in progs:
+        for (aval,) in p.lowered_with:
+            assert isinstance(aval, jax.ShapeDtypeStruct)

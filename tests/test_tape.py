@@ -66,6 +66,66 @@ def test_chain_fusion_is_bit_identical(forest):
     np.testing.assert_array_equal(fused, plain)
 
 
+def test_chain_order_does_not_split_the_program_key(forest):
+    """Conjunctions that differ only in their atoms' selectivities plan in
+    different orders, yet lower to one CHAIN in (column, op) order: one
+    structural key, so one compiled program."""
+    cols = ["elevation_0", "slope_0", "aspect_0", "h_dist_road_0"]
+
+    def conj(sels):
+        return normalize(And([
+            Atom(c, "lt", forest.value_at_selectivity(c, g), selectivity=g)
+            for c, g in zip(cols, sels)]))
+
+    tapes = []
+    for sels in ((0.2, 0.4, 0.6, 0.8), (0.8, 0.6, 0.4, 0.2)):
+        tree = conj(sels)
+        plan = deepfish(tree, PerAtomCostModel(),
+                        total_records=forest.n_records)
+        tapes.append(compile_tape(plan))
+        (chain,) = [op for op in tapes[-1].ops if op.kind == CHAIN]
+        assert [tree.atoms[a].column for a in chain.aids] == sorted(cols)
+        got = DeviceTapeBackend(forest, block=2048).run_tape(tapes[-1])
+        np.testing.assert_array_equal(
+            got, pack_bits(oracle_mask(forest, tree.root)))
+    assert tapes[0].key == tapes[1].key
+
+
+def test_cse_merges_repeated_set_ops():
+    from repro.core.tape import (EMPTY, FULL, OP_AND, OP_ANDNOT, OP_OR,
+                                 TapeOp, _cse)
+    ops = [TapeOp(FULL, 0), TapeOp(EMPTY, 7),
+           TapeOp(ATOM, 1, a=0, aids=(0,)), TapeOp(ATOM, 2, a=0, aids=(1,)),
+           TapeOp(SETOP, 3, a=1, b=2, setop=OP_OR),
+           TapeOp(SETOP, 4, a=2, b=1, setop=OP_OR),    # = s3 (commutes)
+           TapeOp(SETOP, 5, a=3, b=4, setop=OP_AND),   # s3 ∧ s3 = s3
+           TapeOp(SETOP, 6, a=5, b=0, setop=OP_AND),   # s3 ∧ full = s3
+           TapeOp(ATOM, 8, a=6, aids=(2,)),            # reads s3 now
+           TapeOp(SETOP, 9, a=8, b=7, setop=OP_ANDNOT),  # s8 \ empty
+           TapeOp(SETOP, 10, a=7, b=9, setop=OP_OR),   # empty ∨ s8 = s8
+           TapeOp(SETOP, 11, a=10, b=2, setop=OP_ANDNOT)]
+    kept, result = _cse(ops, 11)
+    assert result == 11
+    assert [op.dst for op in kept] == [0, 7, 1, 2, 3, 8, 11]
+    assert kept[5] == TapeOp(ATOM, 8, a=3, aids=(2,))
+    assert kept[6] == TapeOp(SETOP, 11, a=8, b=2, setop=OP_ANDNOT)
+
+
+def test_cse_tapes_match_the_oracle_on_deep_trees(forest):
+    rng = np.random.default_rng(12)
+    be = DeviceTapeBackend(forest, block=2048)
+    for _ in range(4):
+        tree = random_tree(forest, 10, 4, rng)
+        plan = deepfish(tree, PerAtomCostModel(),
+                        total_records=forest.n_records)
+        tape = compile_tape(plan)
+        sets = [(op.setop, op.a, op.b) for op in tape.ops
+                if op.kind == SETOP]
+        assert all(a != b for _, a, b in sets)   # no x ∧ x, x ∨ x left
+        np.testing.assert_array_equal(
+            be.run_tape(tape), pack_bits(oracle_mask(forest, tree.root)))
+
+
 def test_slot_allocation_recycles(forest):
     rng = np.random.default_rng(5)
     tree = random_tree(forest, 8, 3, rng)
@@ -253,6 +313,41 @@ def test_tape_engine_udf_fallback_matches_oracle(string_table):
     np.testing.assert_array_equal(res, want)
     assert be.host_fallbacks > 0
     assert be.records_touched > 0 and be.blocks_touched > 0
+
+
+def test_run_tapes_bundles_programs_and_op_by_op_tapes(string_table):
+    """A batch of an opaque-atom tape between two all-device tapes: the
+    device tapes run one program each (once compiled), the fallback tape
+    op by op, and one bundled sync (after the fallback's own) brings back
+    all three."""
+    from repro.columnar import rewrite_string_atoms
+    from repro.columnar.device import _COMPILER
+    trees = [rewrite_string_atoms(t, string_table)
+             for t in (_mixed_tree(), _udf_tree(), _conj_tree())]
+    tapes = [compile_tape(deepfish(t, PerAtomCostModel(),
+                                   total_records=string_table.n_records))
+             for t in trees]
+    DeviceTapeBackend(string_table, block=1024).run_tapes(tapes)
+    assert _COMPILER.wait(300)      # the programs the batch met compile
+    be = DeviceTapeBackend(string_table, block=1024)
+    device_ok = be._tape_bindings(tapes[1])[4]
+    got = be.run_tapes(tapes)
+    for g, t in zip(got, trees):
+        np.testing.assert_array_equal(
+            g, pack_bits(oracle_mask(string_table, t.root)))
+    fallbacks = device_ok.count(False)
+    assert fallbacks >= 1 and be.host_fallbacks == fallbacks
+    assert be.host_syncs == fallbacks + 1
+    op_by_op = sum(ok and op.kind in (ATOM, CHAIN, SETOP)
+                   for op, ok in zip(tapes[1].ops, device_ok))
+    assert be.cold_tapes == 0
+    assert be.device_dispatches == 2 + op_by_op
+    assert len(be.drain_op_log()) == sum(len(t.costed_ops()) for t in tapes)
+
+
+def _conj_tree():
+    return normalize(And([Atom("x", "gt", -0.5, selectivity=0.7),
+                          Atom("y", "lt", 0.9, selectivity=0.8)]))
 
 
 def test_block_engines_account_fallback_cost_consistently(string_table):

@@ -15,6 +15,7 @@ import pytest
 from repro.columnar import (ExecConfig, QuerySession, StreamQueryError,
                             StreamSession, Tracer, explain_analyze,
                             make_forest_table, random_tree)
+from repro.columnar.device import _COMPILER
 from repro.columnar.drainer import DrainPolicy
 from repro.core import Atom
 from repro.runtime import faults
@@ -164,6 +165,12 @@ def test_stats_protocol_uniform():
 def test_bit_identical_and_contract_equal_with_telemetry(engine, planner,
                                                          forest):
     trees = _trees(forest, 4, seed=3)
+    # a session runs a plan op by op until its program is compiled: warm
+    # the programs first, so both runs below take the same path
+    QuerySession(forest, config=ExecConfig(
+        planner=planner, engine=engine, telemetry=False,
+        trace=False)).execute(trees)
+    assert _COMPILER.wait(300)
     off = QuerySession(forest, config=ExecConfig(
         planner=planner, engine=engine, telemetry=False, trace=False))
     reg, tr = MetricsRegistry(), Tracer()
