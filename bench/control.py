@@ -31,7 +31,8 @@ sys.path[0:1] = [ROOT, os.path.join(ROOT, "src")]
 from bench import reference  # noqa: E402
 
 #: the control offers the cell's load without the set-up's warm-up
-NO_WARMUP = {"warmup_requests": 0, "warmup_bursts": []}
+NO_WARMUP = {"warmup_requests": 0, "warmup_bursts": [],
+             "warmup_compile_wait_s": 0}
 #: ``run_control``'s default: the precision the configuration names
 CONFIGURED = "configured"
 
@@ -95,15 +96,20 @@ def run_control(cell: str, seed: int, seconds: float, *, root: str = ROOT,
     """One run of ``cell`` with the reference in the program's place, in
     the configuration's ``control_dtype`` (``dtype=None``: exact)."""
     from bench import harness
+    bench = harness.load_benchmark(root)
     if dtype == CONFIGURED:
-        bench = harness.load_benchmark(root)
         config = harness.load_config(
             bench, harness.cell_of(bench, cell)["config"], root)
         dtype = config["control_dtype"]
+    no_warmup = dict(NO_WARMUP)
+    if "warmup_replay" in harness.load_traffic(
+            harness.cell_of(bench, cell)["traffic"], root):
+        # the window's queries as the cell draws them, none replayed
+        no_warmup["warmup_replay"] = {}
     return harness.run_cell(
         cell, seed, seconds, False, t_process=time.perf_counter(), root=root,
         config_overrides=config_overrides,
-        traffic_overrides={**NO_WARMUP, **(traffic_overrides or {})},
+        traffic_overrides={**no_warmup, **(traffic_overrides or {})},
         make_system=lambda data: ReferenceSystem(data, dtype),
         log=lambda *a, **k: None)
 

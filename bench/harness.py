@@ -10,8 +10,8 @@ in a file of its own, found by the name ``BENCHMARK.json`` gives it:
 * ``bench/metrics/<metric>.py``: ``read(run)``, one number from the run's
   requests, spans, counters and trace, or None where it finds nothing.
 
-A run: build the data from the seed, open the system, drive
-``warmup_requests`` of the mix (set-up), then the window of ``seconds``;
+A run: build the data from the seed, open the system, warm it up with
+the mix (set-up, ``warm_up``), then drive the window of ``seconds``;
 close the system and compare a sample of the window's answers, drawn from
 the seed, with the plain reference at the state each answer reports.
 """
@@ -19,22 +19,31 @@ from __future__ import annotations
 
 import importlib.util
 import json
+import math
 import os
 import shutil
 import sys
 import tempfile
+import threading
 import time
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
 from bench import reference, tracefile
-from bench.traffic import Mix, Mutations, RefreshThread, Request, drive
+from bench.traffic import (POLL_S, Mix, Mutations, RefreshThread,
+                           Request, drive)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+#: no compile for this long ends the warm-up's wait (a Q19 tape program
+#: traces in well under it between two compiles of the background compiler)
+COMPILE_QUIET_S = 3.0
+#: replayed warm-up queries submitted at once (half the session's
+#: ``max_pending``, so a burst is one drain)
+REPLAY_BURST = 32
 
 
 # ---------------------------------------------------------------------------
@@ -147,16 +156,56 @@ class Run:
 
 class CompileCounter:
     """Programs JAX compiled or loaded from its cache (copied from the
-    bring-up check's ``CompileClock``: JAX's own monitoring events)."""
+    bring-up check's ``CompileClock``: JAX's own monitoring events).  The
+    event is recorded as a scalar when a compile starts and as a duration
+    when it ends, so the counter also knows whether one is running and
+    when the last started or ended."""
 
     def __init__(self):
         import jax
         self.count = 0
+        self._lock = threading.Lock()
+        self._open = 0                  # compiles running
+        self._last = -math.inf          # last start or end
+        jax.monitoring.register_scalar_listener(self._on_start)
         jax.monitoring.register_event_duration_secs_listener(self._on)
+
+    def _mark(self, step: int) -> None:
+        with self._lock:
+            self._open += step
+            self._last = time.perf_counter()
+
+    def _on_start(self, event: str, value: float, **_) -> None:
+        if event == COMPILE_EVENT:
+            self._mark(1)
 
     def _on(self, event: str, secs: float, **_) -> None:
         if event == COMPILE_EVENT:
             self.count += 1
+            self._mark(-1)
+
+    def quiet_since(self) -> Optional[float]:
+        """When the last compile started or ended (-inf if none ever
+        did); None while one is running."""
+        with self._lock:
+            return None if self._open > 0 else self._last
+
+
+def wait_for_compiles(compiles: CompileCounter, cap_s: float,
+                      quiet_s: float = COMPILE_QUIET_S,
+                      poll_s: float = 0.05) -> float:
+    """Wait until no compile has run for ``quiet_s`` seconds (a background
+    compiler traces its next program between two compiles, for less than
+    that), at most ``cap_s`` seconds; returns the seconds waited."""
+    t0 = time.perf_counter()
+    while True:
+        now = time.perf_counter()
+        since = compiles.quiet_since()
+        if since is not None and now - max(since, t0) >= quiet_s:
+            return now - t0
+        if now - t0 >= cap_s:
+            return now - t0
+        time.sleep(poll_s)
 
 
 def device_info() -> dict:
@@ -200,13 +249,18 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
     if "refresh" in traffic:
         refresher = RefreshThread(traffic["refresh"], data, system, muts,
                                   r_refresh)
-        refresher.start()
     try:
-        warm_up(system, mix, traffic, r_warm, muts, late_s)
+        t_warm = time.perf_counter()
+        warm_up(system, mix, traffic, r_warm, muts, late_s, refresher)
+        warmup_s = time.perf_counter() - t_warm
+        compile_wait_s = wait_for_compiles(
+            compiles, float(traffic.get("warmup_compile_wait_s", 0.0)))
         state = {}
         trace_dir = tempfile.mkdtemp(prefix="bench_trace_") if trace else None
 
         def on_start(t0: float) -> None:
+            if refresher is not None:
+                refresher.start(t0, t0 + seconds)
             state["c0"] = system.counters()
             state["compiles0"] = compiles.count
             if trace:
@@ -225,6 +279,8 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
                      on_start=on_start, late_s=late_s)
         t1 = time.perf_counter()
         t0 = state["t0"]
+        if refresher is not None:
+            refresher.join()
         if trace:
             import jax
             state["ann"].__exit__(None, None, None)
@@ -249,10 +305,22 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
         run.counters = {k: c1[k] - c0[k] for k in c1 if k != "backend"}
     fallbacks = host_fallbacks(c0, c1)
     late = [r.submitted - r.due for r in reqs if r.submitted is not None]
+    refresh = {} if refresher is None else {
+        "refresh_pairs": len(refresher.late),
+        "refresh_late_p95_ms": p95_ms(refresher.late),
+        "refresh_pair_s": [round(t, 4) for t in refresher.took]}
+    # where the window's time went, in every run (the traced run's
+    # per-layer metrics read the same spans and counters)
+    span_s = {name: sum(b - a for _, a, b, _ in run.window_spans(name))
+              for name in ("jax.compile", "stream.append", "stream.delete")}
     log(json.dumps({"phase": "window", "requests": len(reqs),
-                    "generator_late_p95_ms":
-                        float(np.percentile(late, 95)) * 1e3 if late else 0.0,
+                    "generator_late_p95_ms": p95_ms(late), **refresh,
+                    "warmup_s": warmup_s, "compile_wait_s": compile_wait_s,
                     "compiles_in_window": compiled,
+                    "compile_ms": span_s["jax.compile"] * 1e3,
+                    "mutation_s": (span_s["stream.append"]
+                                   + span_s["stream.delete"]),
+                    "cold_tapes": (run.counters or {}).get("cold_tapes"),
                     "mutations": muts.done,
                     "degraded_batches": (c1 or {}).get("degraded_batches"),
                     "retries": (c1 or {}).get("retries"),
@@ -290,6 +358,10 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
     return out
 
 
+def p95_ms(seconds: List[float]) -> float:
+    return float(np.percentile(seconds, 95)) * 1e3 if seconds else 0.0
+
+
 def host_fallbacks(c0: Optional[dict], c1: Optional[dict]) -> int:
     """Batches the system did not answer on its device path: the session's
     retried, degraded (re-run on the host) and quarantined ones since it
@@ -305,17 +377,40 @@ def host_fallbacks(c0: Optional[dict], c1: Optional[dict]) -> int:
 
 
 def warm_up(system, mix: Mix, traffic: dict, rng: np.random.Generator,
-            muts: Mutations, late_s: float) -> None:
+            muts: Mutations, late_s: float,
+            refresher: Optional[RefreshThread] = None) -> None:
     """Set-up's share of the traffic, so the drains compile (or load from
     the cache) the programs of the shapes this cell's traffic makes: one
-    burst of ``k`` requests at once for each ``k`` of ``warmup_bursts``
-    (a drain of ``k`` queries each), then ``warmup_requests`` of the mix
-    as it runs."""
+    refresh pair, one burst of ``k`` requests at once for each ``k`` of
+    ``warmup_bursts`` (a drain of ``k`` queries each), the replayed
+    queries of ``warmup_replay``, then ``warmup_requests`` of the mix as
+    it runs."""
+    if refresher is not None:
+        refresher.pair()
     for k in traffic.get("warmup_bursts", []):
         drive(system, mix, {**traffic, "loop": "closed", "streams": int(k)},
               rng, muts, requests=int(k), late_s=late_s)
+    replay = traffic.get("warmup_replay", {})
+    specs = [mix.spec(k, i) for k, m in enumerate(traffic["mix"])
+             for i in range(int(replay.get(m["family"], 0)))]
+    for j in range(0, len(specs), REPLAY_BURST):
+        submit_all(system, specs[j:j + REPLAY_BURST], late_s)
     drive(system, mix, traffic, rng, muts,
           requests=int(traffic["warmup_requests"]), late_s=late_s)
+    mix.rewind()
+
+
+def submit_all(system, specs: Sequence, late_s: float) -> None:
+    """Submit ``specs`` at once and wait for every answer, at most
+    ``late_s`` seconds; a set-up request that fails fails the run."""
+    handles = [system.submit(spec) for spec in specs]
+    deadline = time.perf_counter() + late_s
+    for h in handles:
+        while not h.done():
+            if time.perf_counter() > deadline:
+                raise TimeoutError("a warm-up request was not answered")
+            time.sleep(POLL_S)
+        h.result()
 
 
 def drains_of(spans: List[tuple], reqs: List[Request], t0: float,

@@ -77,9 +77,11 @@ class ProgramSystem:
             self.session.delete(payload)
 
     def counters(self) -> Optional[Dict[str, float]]:
-        """Lifetime counters of the device backend the drains run on, and
-        the session's completed, degraded, retried and quarantined counts
-        (None before the first drain)."""
+        """Lifetime counters of the device backend the drains run on (with
+        ``cold_tapes``, queries it ran op by op while their program
+        compiled, where it counts them), and the session's completed,
+        degraded, retried and quarantined counts (None before the first
+        drain)."""
         from repro.columnar.trace import backend_counters
         res = self.session.last_result
         if res is None:
@@ -87,6 +89,8 @@ class ProgramSystem:
         be = res.backend
         out = backend_counters(be)
         out["records_evaluated"] = float(be.stats.records_evaluated)
+        if hasattr(be, "cold_tapes"):
+            out["cold_tapes"] = float(be.cold_tapes)
         st = self.session.stats
         out["completed"] = float(st.completed)
         out["degraded_batches"] = float(st.degraded_batches)

@@ -14,7 +14,8 @@ from bench import harness  # noqa: E402
 
 NEW = ("queue_wait_ms", "lock_wait_ms_per_drain", "append_ms",
        "compile_ms_in_window", "launches_per_query",
-       "bookkeeping_ms_per_drain", "executor_self_ms_per_drain")
+       "bookkeeping_ms_per_drain", "executor_self_ms_per_drain",
+       "cold_query_share")
 
 # window [10 s, 20 s]: two drains in it, one before it
 SPANS = [
@@ -36,7 +37,7 @@ SPANS = [
     ("jax.compile", 25.0, 25.5, 0),         # after the window
 ]
 
-COUNTERS = {"completed": 3.0, "device_dispatches": 300.0,
+COUNTERS = {"completed": 3.0, "device_dispatches": 300.0, "cold_tapes": 1.0,
             "kernel_invocations": 0.0,
             "kernel_launches": 200.0, "setop_launches": 100.0,
             "bookkeeping_launches": 900.0,
@@ -77,6 +78,15 @@ def test_counter_readers():
         (1000 - 650) / 3)
 
 
+def test_cold_query_share_reads_the_counter_delta():
+    assert _read("cold_query_share", _run()) == pytest.approx(100 / 3)
+    warm = dict(COUNTERS, cold_tapes=0.0)
+    assert _read("cold_query_share", _run(counters=warm)) == 0
+    # a window whose counters saw no drain answer anything
+    idle = dict(COUNTERS, completed=0.0, cold_tapes=0.0)
+    assert _read("cold_query_share", _run(counters=idle)) is None
+
+
 def test_no_compile_in_the_window_reads_zero():
     spans = [s for s in SPANS if s[0] != "jax.compile"]
     assert _read("compile_ms_in_window", _run(spans)) == 0
@@ -88,7 +98,7 @@ def test_a_program_without_the_spans_and_counters_reads_nothing(name):
     request, lock, append or compile spans and no dispatch split."""
     old = [s for s in SPANS if s[0] in ("stream.drain", "batch.dispatch")]
     counters = {k: v for k, v in COUNTERS.items()
-                if not k.endswith(("_launches", "_host_s"))}
+                if not k.endswith(("_launches", "_host_s", "_tapes"))}
     assert _read(name, _run(old, counters)) is None
     assert _read(name, _run([], None)) is None
 

@@ -54,7 +54,9 @@ def root_of(cell, request):
 def tiny_run(cell, seconds=1.0, traffic=None, root=ROOT, **kw):
     config = harness.load_benchmark(root)
     name = harness.cell_of(config, cell)["config"]
-    extra = {"refresh": {"every_s": 0.3, "mutations": ["rf1", "rf2"]}} \
+    extra = {"refresh": {"period_s": 0.3, "mutations": ["rf1", "rf2"]},
+             "warmup_replay": {"q19": 3},
+             "warmup_compile_wait_s": 1.0} \
         if cell == "tpch-throughput" else {}
     if cell.endswith("open"):
         extra["rate_qps"] = 8.0
@@ -82,7 +84,7 @@ def test_reference_agrees_with_engine(cell, request):
 def test_control_is_not_correct(cell, request):
     root = root_of(cell, request)
     name = harness.cell_of(harness.load_benchmark(root), cell)["config"]
-    extra = {"refresh": {"every_s": 0.3, "mutations": ["rf1", "rf2"]}} \
+    extra = {"refresh": {"period_s": 0.3, "mutations": ["rf1", "rf2"]}} \
         if cell == "tpch-throughput" else {}
     size = TINY[name]
     if cell.endswith("open"):

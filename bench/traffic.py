@@ -16,15 +16,33 @@ Keys of a mix file:
     (each request draws a family by ``weight``, default 1) or ``"cycle"``
     (each closed-loop stream walks the list in turn).
 ``refresh`` (optional)
-    ``{"every_s": s, "mutations": [...]}``: a refresh stream that applies
-    the named mutations of the data generator, in order, every ``s``
-    seconds, on a thread of its own.
+    ``{"period_s": p, "mutations": [...]}``: a refresh stream that applies
+    the named mutations of the data generator, in order, as one pair on a
+    thread of its own.  Pairs are due on the window's clock, not paced by
+    the system: the first at ``p / 2`` after the window opens, then every
+    ``p``, so a window of ``s`` seconds holds exactly
+    ``ceil((s - p / 2) / p)`` pairs on every run and seed.  A pair that
+    comes due while the one before is still running starts when that one
+    ends (its lateness is logged), and every pair due in the window is
+    carried out.  Set-up applies one pair before its queries, so that
+    these run on a table that has taken appends and deletes, as the
+    window's do.
 ``warmup_bursts`` (optional)
     sizes ``k``: before the window, ``k`` requests of the mix submitted at
     once for each, so that drains of each size the window makes compile in
     set-up.
+``warmup_replay`` (optional)
+    ``{family: n}``: each family of the mix draws its queries from a
+    stream of its own, so the window's i-th query of a family is known
+    before the window opens; set-up submits the first ``n`` of each named
+    family, a burst at a time, so that the programs of the plan structures
+    the window meets are compiled when it opens.
 ``warmup_requests``
     requests driven with the same mix before the window, as set-up.
+``warmup_compile_wait_s`` (optional)
+    after the warm-up, wait until no program has compiled or loaded (JAX's
+    compile events) for ``COMPILE_QUIET_S``, at most this many seconds, so
+    that the programs the warm-up met are compiled when the window opens.
 ``check_sample``
     answered requests of the window compared with the reference, drawn
     from the seed.
@@ -68,11 +86,33 @@ class Mix:
         w = np.array([float(m.get("weight", 1.0)) for m in traffic["mix"]])
         self.p = w / w.sum()
         self.cycle = traffic.get("order", "weighted") == "cycle"
+        self._queued = self._streams = None
+        if "warmup_replay" in traffic:
+            self._streams = rng.spawn(len(self.draws))
+            self._queued = [[] for _ in self.draws]
+            self._taken = [0] * len(self.draws)
+
+    def spec(self, k: int, i: int):
+        """The ``i``-th query of family ``k`` (a replayed mix)."""
+        queued = self._queued[k]
+        while len(queued) <= i:
+            queued.append(self.draws[k](self._streams[k]))
+        return queued[i]
 
     def next(self, rng: np.random.Generator, position: int):
         if self.cycle:
-            return self.draws[position % len(self.draws)](rng)
-        return self.draws[int(rng.choice(len(self.draws), p=self.p))](rng)
+            k = position % len(self.draws)
+        else:
+            k = int(rng.choice(len(self.draws), p=self.p))
+        if self._queued is None:
+            return self.draws[k](rng)
+        self._taken[k] += 1
+        return self.spec(k, self._taken[k] - 1)
+
+    def rewind(self) -> None:
+        """The window starts again at each family's first query."""
+        if self._queued is not None:
+            self._taken = [0] * len(self.draws)
 
 
 class Mutations:
@@ -86,34 +126,60 @@ class Mutations:
 
 
 class RefreshThread:
-    """Applies the refresh mutations every ``every_s`` seconds."""
+    """Applies the refresh mutations, in order, as one pair due every
+    ``period_s`` seconds on the window's clock (see the mix keys above)."""
 
     def __init__(self, spec: dict, data, system, muts: Mutations,
                  rng: np.random.Generator):
-        self.every_s = float(spec["every_s"])
+        self.period_s = float(spec["period_s"])
         self.names = list(spec["mutations"])
         self.data, self.system, self.muts, self.rng = data, system, muts, rng
-        self._stop = threading.Event()
+        self._t0 = self._end = 0.0
+        self._abort = threading.Event()
+        #: lateness and wall time (seconds) of each pair the thread ran
+        self.late: List[float] = []
+        self.took: List[float] = []
         self.error: Optional[BaseException] = None
-        self._thread = threading.Thread(target=self._loop, name="refresh",
-                                        daemon=True)
+        self.thread = threading.Thread(target=self._loop, name="refresh",
+                                       daemon=True)
 
-    def start(self) -> None:
-        self._thread.start()
+    def pair(self) -> None:
+        """Apply one pair, on the calling thread."""
+        for name in self.names:
+            kind, payload = self.data.mutation(name, self.rng)
+            self.data.mutations.append((kind, payload))
+            self.muts.started += 1
+            self.system.mutate(kind, payload)
+            self.muts.done += 1
+
+    def start(self, t0: float, end: float) -> None:
+        """Pairs are due at ``t0 + period_s / 2``, then every
+        ``period_s``, up to ``end``; the thread ends after the last."""
+        self._t0, self._end = t0, end
+        self.thread.start()
+
+    def join(self) -> None:
+        """Wait until every pair due before ``end`` has been carried out
+        (at once, where it is late)."""
+        self.thread.join()
 
     def stop(self) -> None:
-        self._stop.set()
-        self._thread.join()
+        """End the thread after the pair it is running, if any."""
+        self._abort.set()
+        if self.thread.ident is not None:
+            self.thread.join()
 
     def _loop(self) -> None:
         try:
-            while not self._stop.wait(self.every_s):
-                for name in self.names:
-                    kind, payload = self.data.mutation(name, self.rng)
-                    self.data.mutations.append((kind, payload))
-                    self.muts.started += 1
-                    self.system.mutate(kind, payload)
-                    self.muts.done += 1
+            k = 0
+            while (due := self._t0 + self.period_s * (k + 0.5)) < self._end:
+                if self._abort.wait(max(0.0, due - time.perf_counter())):
+                    return
+                start = time.perf_counter()
+                self.pair()
+                self.late.append(start - due)
+                self.took.append(time.perf_counter() - start)
+                k += 1
         except BaseException as exc:        # surfaced by the harness
             self.error = exc
 
